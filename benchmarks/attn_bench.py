@@ -99,7 +99,8 @@ def _dense_cache(kv, cfg, b, cache_len, key, layout, block_size):
     if layout == "ctg":
         return {**enc, "slot_pos": pos}
     bps = cache_len // block_size
-    cache = {n: x.reshape((b * bps, block_size) + x.shape[2:])
+    # pool order (blocks, KVH, block_size, ...): heads outside the rows
+    cache = {n: x.reshape((b * bps, block_size) + x.shape[2:]).swapaxes(1, 2)
              for n, x in enc.items()}
     cache["pool_pos"] = pos.reshape(b * bps, block_size)
     cache["table"] = jnp.arange(b * bps, dtype=jnp.int32).reshape(b, bps)

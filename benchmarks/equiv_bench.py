@@ -18,6 +18,7 @@ import numpy as np
 from repro.core import bitpack, quant
 from repro.kernels import dispatch, ops, ref
 from repro.kernels.dispatch import GemmConfig
+from repro.launch.mesh import make_mesh
 
 
 def rows(small: bool = False):
@@ -44,7 +45,7 @@ def rows(small: bool = False):
     for ways in (2, 8):
         if ways > n_dev:
             continue
-        mesh = jax.make_mesh((ways,), ("model",))
+        mesh = make_mesh((ways,), ("model",))
         for backend in ("shard-vpu", "shard-mxu"):
             for layout in ("k", "n"):
                 cfg = GemmConfig(backend=backend, mesh=mesh,
@@ -75,7 +76,7 @@ def rows(small: bool = False):
         for backend in backends:
             cfg = GemmConfig(
                 backend=backend,
-                mesh=(jax.make_mesh((2,), ("model",))
+                mesh=(make_mesh((2,), ("model",))
                       if backend.startswith("shard-") else None),
             )
             t0 = time.perf_counter()

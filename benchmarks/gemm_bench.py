@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core import bitpack
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 
 
 def _time(fn, *args, warmup=2, iters=5) -> float:
@@ -250,7 +251,7 @@ def shard_rows(small: bool = False):
     for ways in (1, 2, 4, 8):
         if ways > ndev:
             continue
-        mesh = jax.make_mesh((ways,), ("model",))
+        mesh = make_mesh((ways,), ("model",))
         for inner in ("vpu", "mxu"):
             for layout in ("k", "n"):
                 cfg = GemmConfig(backend=f"shard-{inner}", mesh=mesh,
@@ -376,7 +377,7 @@ def overlap_rows(small: bool = False):
     for ways in (2, 4):
         if ways > ndev:
             continue
-        mesh = jax.make_mesh((ways,), ("model",))
+        mesh = make_mesh((ways,), ("model",))
         for fam in ("vpu", "mxu"):
             base = np.asarray(
                 run(GemmConfig(backend=f"{fam}-k{bits}"), x, w_planes, bits))
@@ -397,7 +398,7 @@ def overlap_rows(small: bool = False):
     # packed-operand and grouped/expert shard paths ride the same ring
     # now (PR 9) — gate each entry point at one split
     ways = 4 if ndev >= 4 else 2
-    mesh = jax.make_mesh((ways,), ("model",))
+    mesh = make_mesh((ways,), ("model",))
     ap = bitpack.pack_sign(jnp.where(x >= 0, 1.0, -1.0))
     wp1 = bitpack.pack_sign(jnp.where(w.T >= 0, 1.0, -1.0))
     base1 = np.asarray(dispatch.packed_gemm(

@@ -106,15 +106,23 @@ def act_codes(x: jax.Array, bits: int) -> jax.Array:
     """DoReFa activation codes: ``round(clip(x, 0, 1) * (2^bits - 1))`` as
     uint32 in [0, 2^bits - 1].  ``quantize_act(x, bits) == codes / n``.
 
-    This function is also called INSIDE the fused quantize->pack Pallas
-    prologue (``kernels/pack_bits.quant_pack_planes_pallas``) on each VMEM
-    tile — pure elementwise jnp, so it traces in a kernel body — which is
-    what guarantees the fused serving prologue and this jnp reference
-    cannot drift.  Note x <= 0 (the dispatch layer's float pad value is
+    Its arithmetic (:func:`act_levels`) is also called INSIDE the fused
+    quantize->pack Pallas prologue
+    (``kernels/pack_bits.quant_pack_planes_pallas``) on each VMEM tile —
+    pure elementwise jnp, so it traces in a kernel body — which is what
+    guarantees the fused serving prologue and this jnp reference cannot
+    drift.  Note x <= 0 (the dispatch layer's float pad value is
     -1.0) maps to code 0: all plane bits 0, contributing nothing to the
     plane GEMM or the row-sums."""
+    return act_levels(x, bits).astype(jnp.uint32)
+
+
+def act_levels(x: jax.Array, bits: int) -> jax.Array:
+    """The DoReFa activation code as a float level in [0, 2^bits - 1] — the
+    arithmetic :func:`act_codes` casts.  The fused Pallas prologue casts it
+    to int32 instead (Mosaic has no float -> uint32 cast)."""
     n = float(2**bits - 1)
-    return jnp.round(_act_unit(x) * n).astype(jnp.uint32)
+    return jnp.round(_act_unit(x) * n)
 
 
 def weight_codes(w: jax.Array, bits: int) -> jax.Array:

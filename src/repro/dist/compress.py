@@ -16,7 +16,7 @@ named axis, and the residual state stays local.  The trainer caller
 (train/trainer.make_sharded_train_step) uses it like this::
 
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def body(params, ef, batch):          # ef leaves: (dp, *param.shape)
         grads = grad_fn(params, batch)    # per-member grads on the shard

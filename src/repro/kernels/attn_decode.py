@@ -12,16 +12,25 @@ under a mask.  Attention was the dominant per-step byte mover (the GEMMs
 are packed; the KV was not).  This kernel reads each mapped cache block
 in place exactly once:
 
-* **grid** ``(B, KVH, ceil(bps / spb))`` — batch x kv-head x split-KV
-  steps, the split axis innermost and sequential, so the online-softmax
-  running state (m, l, acc) lives in VMEM scratch across the splits of
-  one (b, h) pair and the partial-max/sum combine happens as the splits
+* **grid** ``(B, ceil(bps / spb))`` — batch x split-KV steps, the split
+  axis innermost and sequential, so the online-softmax running state
+  (m, l, acc) of every kv-head lives in VMEM scratch across the splits
+  of one slot and the partial-max/sum combine happens as the splits
   retire; the normalised output is written once at the last split.
-* **block tables** — each split step covers ``spb`` table entries of the
-  query's slot.  Unmapped entries (-1: slot shorter than the table, or a
-  retired slot) are skipped at the grid level (``pl.when`` — no loads,
-  no FLOPs), which is also what keeps junk blocks out of the softmax:
-  a skipped block contributes exactly nothing to (m, l, acc).
+* **block tables** — the tables ride in SMEM as scalar prefetch
+  (``pltpu.PrefetchScalarGridSpec``).  Each split step covers ``spb``
+  table entries of the query's slot: the pool is passed ``spb`` times,
+  and operand ``e``'s index map DMAs exactly block ``table[b, j*spb+e]``
+  — VMEM holds ``spb`` blocks, never the pool.  Unmapped entries (-1:
+  slot shorter than the table, or a retired slot) map to block 0, whose
+  repeated index costs no new DMA, and are skipped in the body
+  (``pl.when`` — no FLOPs), which is also what keeps junk blocks out of
+  the softmax: a skipped block contributes exactly nothing to (m, l,
+  acc).
+* **pool order** ``(num_blocks, KVH, block_size, Dh)`` — heads outside
+  the token rows, so one block carries every head (one DMA per table
+  entry) and a head's ``(block_size, Dh)`` tile is a whole-dim Mosaic
+  tile, read by a leading-dim index.
 * **per-row length masking** — ``pool_pos`` rides along per block; rows
   carry -1 for never-written / truncated / write-masked positions and the
   in-kernel mask reproduces ``nn/attention._mask`` exactly (pos >= 0,
@@ -33,19 +42,17 @@ in place exactly once:
   one (block_size, Dh) tile at a time in VMEM — HBM only ever moves the
   narrow codes, 2-4x (int8) to ~16x (1-bit) fewer KV bytes per step.
 
-The contiguous layout routes through the SAME kernel: a ``(B, L, ...)``
-slab reshapes (free) to a ``(B * L/t, t, ...)`` pool with an arange block
-table, where the tile ``t`` is the autotunable split-KV block.  Queries
+The contiguous layout routes through the SAME kernel: a ``(B, L, KVH,
+...)`` slab is transposed to a ``(B * L/t, KVH, t, ...)`` pool with an
+arange block table (one XLA copy of the slab per call — the paged layout
+stores the kernel's order and pays none), where the tile ``t`` is the
+autotunable split-KV block.  Queries
 are a ``(B, C)`` tile — C == 1 is plain decode, C > 1 is the chunked-
 prefill / speculative-verify window (per-row causal masking from the
 absolute positions, exactly like the jnp path).
 
-Like every kernel here it runs in interpret mode on CPU hosts
-(REPRO_PALLAS_INTERPRET, same convention as pack_bits.py).  On real TPUs
-the scalar block-table reads belong in SMEM via
-``pltpu.PrefetchScalarGridSpec`` — a lowering detail the interpret rig
-does not exercise; the dynamic-index loads below are the portable
-spelling.
+Like every kernel here it interprets on the CPU backend and compiles on
+TPU (:func:`repro.kernels.resolve_interpret`).
 
 Numerics vs the gather oracle (``kv.gather`` + ``_sdpa``): scores and
 softmax run in fp32 with the same scale/softcap/mask semantics; only the
@@ -60,26 +67,21 @@ the fp reference.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -2.0e38
+# fp32 scores and PV sums on the MXU too (not one bf16 pass), matching the
+# gather oracle's arithmetic
+_FP32 = jax.lax.Precision.HIGHEST
 
 DEFAULT_CTG_TILE = 512  # contiguous split-KV tile (tokens per grid step)
 DEFAULT_PGD_SPB = 4  # paged table entries per grid step
-
-
-def _env_interpret() -> bool:
-    """Pallas interpret-mode default (shared convention: pack_bits.py)."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
-
-
-def _resolve_interpret(interpret: bool | None) -> bool:
-    return _env_interpret() if interpret is None else bool(interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -101,8 +103,9 @@ def kv_quantize(bits: int, x: jax.Array) -> tuple[jax.Array, jax.Array]:
       (..., KVH, n_groups) per (head, dh-group) — symmetric round-to-
       nearest, absmax/127.
     * bits == 1: sign bytes (..., KVH, Dh/8) uint8 (lane i of byte w is
-      element 8w+i, sign(0) = +1) + per-head fp32 alpha (..., KVH) =
-      mean |x| over Dh (XNOR-Net Eq. 1 applied to the cache).
+      element 8w+i, sign(0) = +1) + per-head fp32 alpha (..., KVH, 1) =
+      mean |x| over Dh (XNOR-Net Eq. 1 applied to the cache) — one scale
+      group, so both tiers' scales are (..., KVH, groups).
     """
     xf = x.astype(jnp.float32)
     dh = x.shape[-1]
@@ -116,7 +119,7 @@ def kv_quantize(bits: int, x: jax.Array) -> tuple[jax.Array, jax.Array]:
     if bits == 1:
         if dh % 8:
             raise ValueError(f"kv_bits=1 needs d_head % 8 == 0, got {dh}")
-        alpha = jnp.abs(xf).mean(axis=-1)
+        alpha = jnp.abs(xf).mean(axis=-1, keepdims=True)
         bits_ = (xf >= 0).astype(jnp.uint8).reshape(*x.shape[:-1], dh // 8, 8)
         weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))
         words = (bits_ * weights).sum(axis=-1, dtype=jnp.uint8)
@@ -138,7 +141,7 @@ def kv_dequantize(bits: int, codes: jax.Array, scale: jax.Array,
         b = (codes[..., None] >> shifts) & jnp.uint8(1)
         signs = (2.0 * b.astype(jnp.float32) - 1.0).reshape(
             *codes.shape[:-1], d_head)
-        return (signs * scale[..., None]).astype(dtype)
+        return (signs * scale).astype(dtype)
     raise ValueError(f"kv_bits must be 8 or 1, got {bits}")
 
 
@@ -155,7 +158,7 @@ def kv_code_shapes(bits: int | None, kvh: int, dh: int, dtype):
     if bits == 1:
         if dh % 8:
             raise ValueError(f"kv_bits=1 needs d_head % 8 == 0, got {dh}")
-        return ((kvh, dh // 8), jnp.uint8), ((kvh,), jnp.float32)
+        return ((kvh, dh // 8), jnp.uint8), ((kvh, 1), jnp.float32)
     raise ValueError(f"kv_bits must be None, 8 or 1, got {bits}")
 
 
@@ -164,81 +167,99 @@ def kv_code_shapes(bits: int | None, kvh: int, dh: int, dtype):
 # ---------------------------------------------------------------------------
 
 
-def _dequant_tile(kv_bits, codes, scale, dh):
-    """One (bs, Dh-coded) VMEM tile -> (bs, Dh) fp32."""
+def _expand_lanes(cols: jax.Array, width: int) -> jax.Array:
+    """(rows, n) -> (rows, n * width): column ``i`` repeated over lanes
+    ``[i*width, (i+1)*width)``.  Built from static width-1 lane slices and
+    selects, which Mosaic lowers; a lane reshape would not."""
+    rows, n = cols.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, n * width), 1)
+    out = jnp.broadcast_to(cols[:, :1], (rows, n * width))
+    for i in range(1, n):
+        out = jnp.where(lane >= i * width, cols[:, i:i + 1], out)
+    return out
+
+
+def _dequant_tile(kv_bits, ref, scale_ref, h, dh):
+    """Head ``h`` of one pool block -> (bs, Dh) fp32.  The same arithmetic
+    as :func:`kv_dequantize`, spelled in whole-tile ops."""
+    codes = ref[0, h]  # (bs, Dh-coded)
     if kv_bits is None:
         return codes.astype(jnp.float32)
-    return kv_dequantize(kv_bits, codes, scale, dh, jnp.float32)
+    scale = scale_ref[0, h]  # (bs, groups) fp32
+    if kv_bits == 8:
+        vals = codes.astype(jnp.float32)
+    else:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], dh), 1)
+        byte = _expand_lanes(codes.astype(jnp.int32), 8)
+        vals = 2.0 * ((byte >> (lane % 8)) & 1).astype(jnp.float32) - 1.0
+    return vals * _expand_lanes(scale, dh // scale.shape[1])
 
 
-def _make_kernel(*, c, g, dh, bs, bps, spb, n_steps, kv_bits, sm_scale,
-                 cap, causal, window):
+def _make_kernel(*, kvh, cg, dh, bps, spb, n_steps, kv_bits, sm_scale, cap,
+                 causal, window):
     """Build the flash-decode kernel body for one static configuration.
 
-    Ref order: table, q_pos, q, [pool_k, (k_scale)], [pool_v, (v_scale)],
-    pool_pos, out, then scratch m/l/acc.  All compile-time shape knobs
-    arrive through the closure — the repo's kernels are traced per jitted
+    Ref order: table (SMEM scalar prefetch), q, q_pos, then per block
+    operand ``e < spb``: pool_k, (k_scale), pool_v, (v_scale), pool_pos;
+    then out and the m/l/acc scratch.  All compile-time shape knobs arrive
+    through the closure — the repo's kernels are traced per jitted
     configuration anyway.
     """
-    cg = c * g
+    per = 3 if kv_bits is None else 5
 
-    def kernel(tab_ref, qp_ref, q_ref, *refs):
-        if kv_bits is None:
-            pk_ref, pv_ref, pp_ref, o_ref, m_ref, l_ref, acc_ref = refs
-            ks_ref = vs_ref = None
-        else:
-            (pk_ref, ks_ref, pv_ref, vs_ref, pp_ref, o_ref,
-             m_ref, l_ref, acc_ref) = refs
-        h = pl.program_id(1)
-        j = pl.program_id(2)
+    def kernel(tab_ref, q_ref, qp_ref, *refs):
+        blocks = [refs[e * per:(e + 1) * per] for e in range(spb)]
+        o_ref, m_ref, l_ref, acc_ref = refs[spb * per:]
+        b = pl.program_id(0)
+        j = pl.program_id(1)
 
         @pl.when(j == 0)
         def _init():
-            m_ref[...] = jnp.full((cg, 1), NEG_INF, jnp.float32)
-            l_ref[...] = jnp.zeros((cg, 1), jnp.float32)
-            acc_ref[...] = jnp.zeros((cg, dh), jnp.float32)
+            m_ref[...] = jnp.full((kvh, cg, 1), NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros((kvh, cg, 1), jnp.float32)
+            acc_ref[...] = jnp.zeros((kvh, cg, dh), jnp.float32)
 
-        qt = q_ref[0, :, 0, :, :].reshape(cg, dh).astype(jnp.float32)
-        qp = jnp.repeat(qp_ref[0, :], g).reshape(cg, 1)
+        qp = qp_ref[0]  # (cg, 1) absolute query positions
 
-        for e in range(spb):
+        for e, blk_refs in enumerate(blocks):
+            if kv_bits is None:
+                pk_ref, pv_ref, pp_ref = blk_refs
+                ks_ref = vs_ref = None
+            else:
+                pk_ref, ks_ref, pv_ref, vs_ref, pp_ref = blk_refs
             jj = j * spb + e
-            jjc = jnp.minimum(jj, bps - 1)
-            blk = tab_ref[0, jjc]
             # grid-level skip: unmapped (-1) table entries and the ragged
             # tail of the last split step cost nothing and add nothing
-            mapped = (jj < bps) & (blk >= 0)
+            mapped = (jj < bps) & (tab_ref[b, jnp.minimum(jj, bps - 1)] >= 0)
 
             @pl.when(mapped)
-            def _accumulate():
-                # head indexing happens HERE, not in the pool BlockSpecs:
-                # grid-invariant full-pool blocks let the interpret rig's
-                # XLA while-loop hoist the pool materialisation out of the
-                # grid loop (a per-head BlockSpec slice would be a strided
-                # copy per grid step); a TPU lowering would instead DMA
-                # `tab[b, jj]`-indexed blocks via PrefetchScalarGridSpec.
-                ksc = None if ks_ref is None else ks_ref[blk, :, h]
-                vsc = None if vs_ref is None else vs_ref[blk, :, h]
-                kt = _dequant_tile(kv_bits, pk_ref[blk, :, h, :], ksc, dh)
-                vt = _dequant_tile(kv_bits, pv_ref[blk, :, h, :], vsc, dh)
-                kp = pp_ref[blk, :].reshape(1, bs)
-                s = jnp.dot(qt, kt.T,
-                            preferred_element_type=jnp.float32) * sm_scale
-                if cap is not None:
-                    s = cap * jnp.tanh(s / cap)
-                valid = kp >= 0  # empty / truncated rows carry pos -1
+            def _accumulate(pk_ref=pk_ref, ks_ref=ks_ref, pv_ref=pv_ref,
+                            vs_ref=vs_ref, pp_ref=pp_ref):
+                kp = pp_ref[0]  # (1, bs); empty / truncated rows carry -1
+                valid = kp >= 0
                 if causal:
                     valid &= kp <= qp
                 if window is not None:
                     valid &= kp > qp - window
-                s = jnp.where(valid, s, NEG_INF)
-                m_new = jnp.maximum(m_ref[...], s.max(axis=1, keepdims=True))
-                alpha = jnp.exp(m_ref[...] - m_new)
-                p = jnp.exp(s - m_new)
-                l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-                acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-                    p, vt, preferred_element_type=jnp.float32)
-                m_ref[...] = m_new
+                for h in range(kvh):
+                    qt = q_ref[0, h].astype(jnp.float32)  # (cg, Dh)
+                    kt = _dequant_tile(kv_bits, pk_ref, ks_ref, h, dh)
+                    vt = _dequant_tile(kv_bits, pv_ref, vs_ref, h, dh)
+                    s = jax.lax.dot_general(
+                        qt, kt, (((1,), (1,)), ((), ())), precision=_FP32,
+                        preferred_element_type=jnp.float32) * sm_scale
+                    if cap is not None:
+                        s = cap * jnp.tanh(s / cap)
+                    s = jnp.where(valid, s, NEG_INF)
+                    m_prev = m_ref[h]
+                    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.exp(s - m_new)
+                    l_ref[h] = l_ref[h] * alpha + p.sum(axis=1, keepdims=True)
+                    acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                        p, vt, precision=_FP32,
+                        preferred_element_type=jnp.float32)
+                    m_ref[h] = m_new
 
         @pl.when(j == n_steps - 1)
         def _finish():
@@ -246,8 +267,7 @@ def _make_kernel(*, c, g, dh, bs, bps, spb, n_steps, kv_bits, sm_scale,
             # merged by the running rescale; normalise and emit.  Fully
             # masked rows (l == 0: empty slot) emit zeros — callers only
             # consume active rows (same contract as write_mask).
-            out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-37)
-            o_ref[...] = out.reshape(1, c, 1, g, dh)
+            o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-37)
 
     return kernel
 
@@ -260,10 +280,10 @@ def flash_decode_paged(
     table: jax.Array,  # (B, bps) int32 block ids, -1 = unmapped
     q: jax.Array,  # (B, C, KVH, G, Dh)
     q_pos: jax.Array,  # (B, C) int32 absolute query positions
-    pool_k: jax.Array,  # (nb, bs, KVH, Dh) fp | int8 codes | uint8 signs
+    pool_k: jax.Array,  # (nb, KVH, bs, Dh) fp | int8 codes | uint8 signs
     pool_v: jax.Array,
     pool_pos: jax.Array,  # (nb, bs) int32, -1 = empty
-    k_scale: jax.Array | None = None,  # (nb, bs, KVH[, groups]) fp32
+    k_scale: jax.Array | None = None,  # (nb, KVH, bs, groups) fp32
     v_scale: jax.Array | None = None,
     *,
     block_size: int,
@@ -283,57 +303,56 @@ def flash_decode_paged(
     nb, bs = pool_pos.shape
     bps = table.shape[1]
     assert bs == block_size, (bs, block_size)
-    spb = blocks_per_step or min(DEFAULT_PGD_SPB, bps)
+    spb = min(blocks_per_step or DEFAULT_PGD_SPB, bps)
     n_steps = -(-bps // spb)
     cg = c * g
 
-    grid = (b, kvh, n_steps)
-    code_dh = pool_k.shape[-1]
+    # head-major query/output tiles: (B, KVH, C*G, Dh), row = c*G + g
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(b, kvh, cg, dh)
+    qp = jnp.repeat(q_pos.astype(jnp.int32), g, axis=1)[:, :, None]
+
+    def blk_map(e, nd):
+        def index(b_, j, tab):
+            blk = tab[b_, jnp.minimum(j * spb + e, bps - 1)]
+            return (jnp.maximum(blk, 0),) + (0,) * (nd - 1)
+        return index
+
     in_specs = [
-        pl.BlockSpec((1, bps), lambda b_, h, j: (b_, 0)),
-        pl.BlockSpec((1, c), lambda b_, h, j: (b_, 0)),
-        pl.BlockSpec((1, c, 1, g, dh), lambda b_, h, j: (b_, 0, h, 0, 0)),
+        pl.BlockSpec((1, kvh, cg, dh), lambda b_, j, tab: (b_, 0, 0, 0)),
+        pl.BlockSpec((1, cg, 1), lambda b_, j, tab: (b_, 0, 0)),
     ]
-    operands = [table, q_pos, q]
-    # pool blocks are the FULL arrays at a grid-invariant index — the
-    # kernel body does the (block, head) indexing, so the interpret rig
-    # hoists the pool materialisation out of the grid loop (see kernel)
-    pool_spec = pl.BlockSpec((nb, bs, kvh, code_dh),
-                             lambda b_, h, j: (0, 0, 0, 0))
-    if kv_bits is None:
-        in_specs += [pool_spec, pool_spec]
-        operands += [pool_k, pool_v]
-    else:
-        if kv_bits == 8:
-            ng = kv_scale_groups(dh)
-            sc_spec = pl.BlockSpec((nb, bs, kvh, ng),
-                                   lambda b_, h, j: (0, 0, 0, 0))
-        else:
-            sc_spec = pl.BlockSpec((nb, bs, kvh),
-                                   lambda b_, h, j: (0, 0, 0))
-        in_specs += [pool_spec, sc_spec, pool_spec, sc_spec]
-        operands += [pool_k, k_scale, pool_v, v_scale]
-    in_specs.append(pl.BlockSpec((nb, bs), lambda b_, h, j: (0, 0)))
-    operands.append(pool_pos)
+    operands = [qh, qp]
+    leaves = [pool_k, pool_v] if kv_bits is None else \
+        [pool_k, k_scale, pool_v, v_scale]
+    leaves.append(pool_pos.reshape(nb, 1, bs))
+    for e in range(spb):
+        for leaf in leaves:
+            in_specs.append(pl.BlockSpec((1,) + leaf.shape[1:],
+                                         blk_map(e, leaf.ndim)))
+            operands.append(leaf)
 
     kernel = _make_kernel(
-        c=c, g=g, dh=dh, bs=bs, bps=bps, spb=spb, n_steps=n_steps,
+        kvh=kvh, cg=cg, dh=dh, bps=bps, spb=spb, n_steps=n_steps,
         kv_bits=kv_bits, sm_scale=sm_scale, cap=logit_softcap,
         causal=causal, window=window)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c, 1, g, dh),
-                               lambda b_, h, j: (b_, 0, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c, kvh, g, dh), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((cg, 1), jnp.float32),
-            pltpu.VMEM((cg, 1), jnp.float32),
-            pltpu.VMEM((cg, dh), jnp.float32),
-        ],
-        interpret=_resolve_interpret(interpret),
-    )(*operands)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_steps),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, kvh, cg, dh),
+                                   lambda b_, j, tab: (b_, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((kvh, cg, 1), jnp.float32),
+                pltpu.VMEM((kvh, cg, 1), jnp.float32),
+                pltpu.VMEM((kvh, cg, dh), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, cg, dh), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(table.astype(jnp.int32), *operands)
+    return out.reshape(b, kvh, c, g, dh).transpose(0, 2, 1, 3, 4)
 
 
 def flash_decode_contig(
@@ -353,18 +372,20 @@ def flash_decode_contig(
     kv_tile: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Contiguous-slab variant: the per-slot ``(B, L, ...)`` slab is a
-    pool of ``L / t`` tiles per slot under an arange block table — a free
-    reshape, after which the SAME paged kernel runs.  ``kv_tile`` is the
-    split-KV tile (autotuned via :func:`select_attn_tiles`)."""
+    """Contiguous-slab variant: the per-slot ``(B, L, KVH, ...)`` slab is
+    a pool of ``L / t`` tiles per slot under an arange block table (one
+    transpose into the kernel's ``(blocks, KVH, t, ...)`` order), after
+    which the SAME paged kernel runs.  ``kv_tile`` is the split-KV tile
+    (autotuned via :func:`select_attn_tiles`)."""
     b, l = slot_pos.shape
     t = kv_tile or DEFAULT_CTG_TILE
     while l % t:  # tile must divide the slab; fall back toward 1
         t //= 2
     nt = l // t
 
-    def pooled(x):
-        return x.reshape(b * nt, t, *x.shape[2:])
+    def pooled(x):  # (B, L, KVH, d) -> (B*nt, KVH, t, d)
+        x = x.reshape(b * nt, t, *x.shape[2:])
+        return x if x.ndim == 2 else x.swapaxes(1, 2)
 
     table = jnp.arange(b * nt, dtype=jnp.int32).reshape(b, nt)
     return flash_decode_paged(
@@ -444,8 +465,9 @@ def autotune_attn_tiles(b: int, c: int, cache_len: int, kvh: int, dh: int,
         nt = cache_len // bs
         table = jnp.arange(b * nt, dtype=jnp.int32).reshape(b, nt)
         return flash_decode_paged(
-            table, q, q_pos, kf.reshape(b * nt, bs, kvh, dh),
-            vf.reshape(b * nt, bs, kvh, dh), pos.reshape(b * nt, bs),
+            table, q, q_pos, kf.reshape(b * nt, bs, kvh, dh).swapaxes(1, 2),
+            vf.reshape(b * nt, bs, kvh, dh).swapaxes(1, 2),
+            pos.reshape(b * nt, bs),
             block_size=bs, kv_bits=None, sm_scale=sm, blocks_per_step=s,
             interpret=interpret)
 

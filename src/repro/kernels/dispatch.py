@@ -109,8 +109,9 @@ through the SAME fused epilogue as every other path — which is what keeps
 w4a4/w8a8 packed serving numerically aligned with the fake-quant train
 path (§2.2.2's argument, generalized from 1 bit to the 2..31 family).
 
-On this CPU container Pallas runs in interpret mode; on a real TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or ``GemmConfig(interpret=False)``).
+Pallas kernels compile with Mosaic on TPU and run in the interpreter on
+the CPU backend (:func:`repro.kernels.resolve_interpret`); every tile this
+module selects obeys Mosaic's block rule (:func:`legal_tiles`).
 """
 
 from __future__ import annotations
@@ -123,11 +124,13 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
+
 from repro.core import bitpack, quant
 from repro.core.policy import QuantSpec
 from repro.dist.sharding import packed_gemm_pspecs
-from repro.kernels import ref
+from repro.kernels import LANES, SUBLANES, ref, resolve_interpret
 from repro.kernels.kbit_gemm import (
     kbit_plane_gemm_batched_pallas,
     kbit_plane_gemm_pallas,
@@ -136,12 +139,9 @@ from repro.kernels.kbit_mxu import (
     kbit_mxu_gemm_batched_pallas,
     kbit_mxu_gemm_pallas,
 )
-from repro.kernels.pack_bits import (
-    _env_interpret,
-    pack_sign_pallas,
-    quant_pack_planes_pallas,
-)
+from repro.kernels.pack_bits import pack_sign_pallas, quant_pack_planes_pallas
 from repro.kernels.xnor_gemm import (
+    DEFAULT_CHUNK_WORDS,
     mxu_pad_inflation,
     xnor_dot_mxu_batched_pallas,
     xnor_dot_mxu_pallas,
@@ -150,8 +150,6 @@ from repro.kernels.xnor_gemm import (
 )
 
 WORD_BITS = bitpack.WORD_BITS
-# _env_interpret is shared with the pack kernels (repro.kernels.pack_bits)
-# so the two modules cannot drift on how REPRO_PALLAS_INTERPRET is read.
 
 
 # ---------------------------------------------------------------------------
@@ -172,34 +170,14 @@ class TileConfig:
 
 
 # Row-tile ladder: smallest entry >= the operand dim wins (last entry caps).
-# K-word ladder likewise.  Separate rows per backend: the MXU kernel unpacks
-# to (rows, bkw*32) int8 in VMEM so its K-step is kept smaller; the VPU
-# popcount kernel streams words and tolerates a deeper K-block.
-# The ladders start at 1 row so DECODE shapes (M = batch of 1..7 serving
-# requests) clamp bm to next-pow2(M) instead of padding up to an 8-row
-# tile — a decode GEMM at M=1 otherwise wastes 8x the VMEM rows and grid
-# work on padding.
-_DECODE_ROWS = (1, 2, 4, 8, 16, 32, 64, 128)
-_TILE_TABLE: dict[str, dict[str, tuple[int, ...]]] = {
-    "vpu": {"rows": _DECODE_ROWS, "kw": (8, 16, 32, 64)},
-    "mxu": {"rows": _DECODE_ROWS, "kw": (8, 16, 32)},
-    # k-bit plane backends stream ka+kb plane stacks per block, so the
-    # K-step shrinks as the plane count grows (VMEM per block scales with
-    # (ka + kb) * bkw words).
-    "vpu-k2": {"rows": _DECODE_ROWS, "kw": (8, 16, 32)},
-    "vpu-k4": {"rows": _DECODE_ROWS, "kw": (8, 16, 32)},
-    "vpu-k8": {"rows": _DECODE_ROWS, "kw": (8, 16)},
-    # int8 code-lane MXU k-bit backends (kernels/kbit_mxu.py): both
-    # operands unpack to (rows, bkw*32) int8 in VMEM, so the K-step
-    # matches the 1-bit MXU ladder (k8 keeps it shallower — two 8-plane
-    # stacks stream per block on top of the int8 lanes, and the unpack
-    # intermediates scale with plane count x bkw, so k8 also offers a
-    # bkw=4 step that keeps them resident in the fastest tile memory).
-    "mxu-k2": {"rows": _DECODE_ROWS, "kw": (8, 16, 32)},
-    "mxu-k4": {"rows": _DECODE_ROWS, "kw": (8, 16, 32)},
-    "mxu-k8": {"rows": _DECODE_ROWS, "kw": (4, 8, 16)},
-}
-_DEFAULT_CHUNK_WORDS = 8
+# K-word ladder likewise.  The ladders start at 1 row so DECODE shapes (M =
+# batch of 1..7 serving requests) clamp bm to next-pow2(M) instead of
+# padding up to an 8-row tile.  Both are Mosaic-legal by construction: an
+# entry smaller than the operand dim is only ever the LAST one (128 rows /
+# 128 words — whole lane and sublane tiles), and an entry at least as big
+# as the operand dim IS the padded dim.  Every backend shares them.
+_ROWS = (1, 2, 4, 8, 16, 32, 64, 128)
+_KW = (8, 16, 32, 64, LANES)
 
 
 def _pick(size: int, ladder: tuple[int, ...]) -> int:
@@ -219,21 +197,44 @@ def _chunk_for(bkw: int, want: int) -> int:
     return cw
 
 
+def legal_tiles(t: TileConfig, m: int, n: int, kw: int) -> TileConfig:
+    """Screen a tile for (M, N, Kw) operands against Mosaic's block rule.
+
+    Operands are padded up to the tile, so a tile at least as big as its
+    dim is the whole (padded) dim and always legal.  A smaller one must be
+    whole hardware tiles: bm (sublanes of the activation and output
+    blocks) a multiple of 8, bn (lanes of the output block) and bkw (lanes
+    of both word blocks) multiples of 128.  The VPU lane chunk must be a
+    multiple of 128 dividing bkw, or bkw itself.  Legal tiles pass through
+    unchanged; this is what keeps a cached winner measured under the
+    interpreter from reaching the compiler."""
+    bm = t.bm if t.bm >= m or t.bm % SUBLANES == 0 else \
+        _round_up(t.bm, SUBLANES)
+    bn = t.bn if t.bn >= n or t.bn % LANES == 0 else _round_up(t.bn, LANES)
+    bkw = t.bkw if t.bkw >= kw or t.bkw % LANES == 0 else \
+        min(_round_up(t.bkw, LANES), kw)
+    cw = t.chunk_words
+    if bkw % LANES:
+        cw = bkw
+    elif cw % LANES or bkw % cw:
+        cw = LANES
+    return TileConfig(bm=bm, bn=bn, bkw=bkw, chunk_words=cw)
+
+
 @functools.lru_cache(maxsize=None)
 def select_tiles(m: int, n: int, kw: int, backend: str) -> TileConfig:
-    """(M, N, Kw) -> tile sizes for ``backend``: a measured autotune-cache
-    winner when one exists (:func:`autotune_tiles`), else the heuristic
-    table."""
+    """(M, N, Kw) -> Mosaic-legal tile sizes for ``backend``: a measured
+    autotune-cache winner when one exists (:func:`autotune_tiles`),
+    screened by :func:`legal_tiles`, else the heuristic ladders."""
     tuned = _tuned_tiles().get((m, n, kw, backend))
     if tuned is not None:
-        return tuned
-    rule = _TILE_TABLE.get(backend, _TILE_TABLE["vpu"])
-    bkw = _pick(kw, rule["kw"])
+        return legal_tiles(tuned, m, n, kw)
+    bkw = _pick(kw, _KW)
     return TileConfig(
-        bm=_pick(m, rule["rows"]),
-        bn=_pick(n, rule["rows"]),
+        bm=_pick(m, _ROWS),
+        bn=_pick(n, _ROWS),
         bkw=bkw,
-        chunk_words=_chunk_for(bkw, _DEFAULT_CHUNK_WORDS),
+        chunk_words=_chunk_for(bkw, DEFAULT_CHUNK_WORDS),
     )
 
 
@@ -293,19 +294,19 @@ def _save_tile_cache(path: str) -> None:
 def _tile_candidates(m: int, n: int, kw: int, backend: str):
     """Candidate tiles around the heuristic pick: the heuristic itself,
     the neighbouring row-tile steps, and the full K-word ladder — a small
-    set (<= ~2*2*4) so autotuning one shape stays cheap."""
-    rule = _TILE_TABLE.get(backend, _TILE_TABLE["vpu"])
+    set (<= ~2*2*5) so autotuning one shape stays cheap."""
+    del backend
 
     def near(size: int, ladder: tuple[int, ...]):
         i = ladder.index(_pick(size, ladder))
         return sorted({ladder[i], ladder[min(i + 1, len(ladder) - 1)]})
 
-    for bm in near(m, rule["rows"]):
-        for bn in near(n, rule["rows"]):
-            for bkw in rule["kw"]:
+    for bm in near(m, _ROWS):
+        for bn in near(n, _ROWS):
+            for bkw in _KW:
                 yield TileConfig(bm=bm, bn=bn, bkw=bkw,
                                  chunk_words=_chunk_for(
-                                     bkw, _DEFAULT_CHUNK_WORDS))
+                                     bkw, DEFAULT_CHUNK_WORDS))
 
 
 def autotune_tiles(
@@ -400,8 +401,8 @@ class GemmConfig:
     wrappers) that do not thread a QuantSpec — explicit ``w_bits``/
     ``a_bits`` arguments on the entry points take precedence.
 
-    ``interpret=None`` reads REPRO_PALLAS_INTERPRET (default: interpret,
-    the only mode available on this CPU container).  The flag governs the
+    ``interpret=None`` follows the platform
+    (:func:`repro.kernels.resolve_interpret`); the flag governs the
     activation-prologue pack kernels too, not just the GEMM kernels.
 
     ``fused_prologue`` selects the one-pass Pallas quantize->pack kernels
@@ -470,12 +471,12 @@ class GemmConfig:
             bn=self.bn or t.bn,
             bkw=bkw,
             chunk_words=_chunk_for(bkw, self.chunk_words
-                                   or _DEFAULT_CHUNK_WORDS),
+                                   or DEFAULT_CHUNK_WORDS),
         )
 
     @property
     def _interpret(self) -> bool:
-        return self.interpret if self.interpret is not None else _env_interpret()
+        return resolve_interpret(self.interpret)
 
 
 DEFAULT_GEMM_CONFIG = GemmConfig()
@@ -1333,9 +1334,14 @@ def _shard_kbit_gemm_grouped(family, buckets, w_stack, tiles, config):
 # "k" layout word-aligns the float K split (each shard's slab is a whole
 # number of packed words), so the words each shard packs are EXACTLY the
 # global packed words of that slab and results stay bit-identical — but
-# the global-pack-then-reshard hop is gone: floats shard once, and only
-# local slabs are quantized+packed.  The "n" layout packs once (fused)
-# and broadcasts the packed words.  Float pad is -1.0: bit 0 at 1 bit,
+# the global-pack-then-reshard hop is gone: only local slabs are
+# quantized+packed.  The floats enter the body replicated (every shard
+# holds them already) and each shard slices its own slab there
+# (:func:`_sliced_k`): declared K-sharded at the shard_map boundary, they
+# let the partitioner shard the producers' float math (the RMSNorm sum of
+# squares) over the same axis, which reorders its fp32 sums and moved
+# sharded logits off single-device on TPU.  The "n" layout packs once
+# (fused) and broadcasts the packed words.  Float pad is -1.0: bit 0 at 1 bit,
 # code 0 after the DoReFa clip — zero words in both operands either way.
 
 
@@ -1352,6 +1358,19 @@ def _pad_k_float(x: jax.Array, k_pad: int) -> jax.Array:
         return x
     widths = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
     return jnp.pad(x, widths, constant_values=-1.0)  # bit 0 / code 0
+
+
+def _sliced_k(body, axis: str, kw_loc: int):
+    """Wrap a "k"-layout shard_map body to take the padded float
+    activations replicated and slice this shard's word-aligned K slab."""
+    width = kw_loc * WORD_BITS
+
+    def wrapped(x_full, *rest):
+        start = jax.lax.axis_index(axis) * width
+        return body(jax.lax.dynamic_slice_in_dim(x_full, start, width,
+                                                 axis=1), *rest)
+
+    return wrapped
 
 
 def _ring_chunk_reduce(compute_chunk, *, axis, ns, nc):
@@ -1426,8 +1445,9 @@ def _shard_from_float(inner, x2, w_packed, k_true, config):
             return _ring_chunk_reduce(chunk, axis=part.reduce_axis, ns=ns,
                                       nc=nc)
 
-        raw = shard_map(body_ring, mesh=mesh, in_specs=(part.a, part.w),
-                        out_specs=part.out, check_vma=False)(x_p, w_p)
+        raw = shard_map(_sliced_k(body_ring, axis, kw_loc), mesh=mesh,
+                        in_specs=(P(), part.w), out_specs=part.out,
+                        check_vma=False)(x_p, w_p)
         raw = raw[:, :n]
         if inner == "vpu":
             return k_true - 2 * raw
@@ -1441,8 +1461,9 @@ def _shard_from_float(inner, x2, w_packed, k_true, config):
             return jax.lax.psum(_vpu_raw(ap, b_loc, t, interp),
                                 part.reduce_axis)
 
-        mism = shard_map(body_vpu, mesh=mesh, in_specs=(part.a, part.w),
-                         out_specs=part.out, check_vma=False)(x_p, w_p)
+        mism = shard_map(_sliced_k(body_vpu, axis, kw_loc), mesh=mesh,
+                         in_specs=(P(), part.w), out_specs=part.out,
+                         check_vma=False)(x_p, w_p)
         return k_true - 2 * mism
 
     def body_mxu(a_loc, b_loc):
@@ -1450,8 +1471,9 @@ def _shard_from_float(inner, x2, w_packed, k_true, config):
         dot, _ = _mxu_raw(ap, b_loc, t, interp)
         return jax.lax.psum(dot, part.reduce_axis)
 
-    dot = shard_map(body_mxu, mesh=mesh, in_specs=(part.a, part.w),
-                    out_specs=part.out, check_vma=False)(x_p, w_p)
+    dot = shard_map(_sliced_k(body_mxu, axis, kw_loc), mesh=mesh,
+                    in_specs=(P(), part.w), out_specs=part.out,
+                    check_vma=False)(x_p, w_p)
     # every shard contracted round_up(kw_loc, bkw) words; correct ONCE
     return dot - mxu_pad_inflation(ns * _round_up(kw_loc, t.bkw), k_true)
 
@@ -1500,8 +1522,8 @@ def _shard_kbit_from_float(family, x2, w_planes, a_bits, w_bits, k_true,
                                        ns=ns, nc=nc)
             return s_loc, jax.lax.psum(t_loc, part.reduce_axis)
 
-        s, t_sum = shard_map(body_ring, mesh=mesh,
-                             in_specs=(part.a, part.w),
+        s, t_sum = shard_map(_sliced_k(body_ring, axis, kw_loc), mesh=mesh,
+                             in_specs=(P(), part.w),
                              out_specs=(part.out, part.out),
                              check_vma=False)(x_p, w_p)
         return _kbit_dequant(s[:, :n], t_sum, a_bits, w_bits)
@@ -1514,7 +1536,8 @@ def _shard_kbit_from_float(family, x2, w_planes, a_bits, w_bits, k_true,
         return (jax.lax.psum(s_loc, part.reduce_axis),
                 jax.lax.psum(t_loc, part.reduce_axis))
 
-    s, t_sum = shard_map(body, mesh=mesh, in_specs=(part.a, part.w),
+    s, t_sum = shard_map(_sliced_k(body, axis, kw_loc), mesh=mesh,
+                         in_specs=(P(), part.w),
                          out_specs=(part.out, part.out),
                          check_vma=False)(x_p, w_p)
     return _kbit_dequant(s, t_sum, a_bits, w_bits)
@@ -1588,13 +1611,33 @@ for _fam in ("vpu", "mxu"):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bkw", "use_pallas",
-                                             "interpret"))
+def _prologue_tiles(m: int, kw: int) -> tuple[int, int]:
+    """Mosaic-legal (bm, bkw) for the pack kernels: all rows up to 8, else
+    8-row blocks; all words up to 128, else 128-word (one lane tile)
+    blocks.  Small blocks keep the 32-sublane float input tile of the
+    word-major view (kernels/pack_bits.py) well inside scoped VMEM."""
+    return (m if m <= SUBLANES else SUBLANES,
+            kw if kw <= LANES else LANES)
+
+
+def _pad_for_pack(x: jax.Array):
+    """Pad (M, K) floats to the prologue tiles with -1.0 (bit 0 / code
+    0); returns (padded x, bm, bkw)."""
+    m, k = x.shape
+    bm, bkw = _prologue_tiles(m, bitpack.packed_width(k))
+    kb = bkw * WORD_BITS
+    xp = jnp.pad(
+        x,
+        ((0, _round_up(m, bm) - m), (0, _round_up(k, kb) - k)),
+        constant_values=-1.0,
+    )
+    return xp, bm, bkw
+
+
+@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def pack_activations(
     x: jax.Array,
     *,
-    bm: int = 8,
-    bkw: int = 8,
     use_pallas: bool = True,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -1603,32 +1646,22 @@ def pack_activations(
     Rows are NOT padded (output keeps M); K tail bits are 0.
     ``use_pallas=False`` is the jnp reference (``bitpack.pack_sign``) —
     bit-identical, kept as the equivalence oracle (PrologueSpec.fused).
-    ``interpret=None`` reads REPRO_PALLAS_INTERPRET; callers on the
-    dispatch path thread ``GemmConfig.interpret`` so a real-TPU config
-    compiles the pack stage like the GEMM kernels.
+    Callers on the dispatch path thread ``GemmConfig.interpret``.
     """
     m, k = x.shape
-    kw = bitpack.packed_width(k)
     if not use_pallas:
         return bitpack.pack_sign(x)
-    kb = bkw * WORD_BITS
-    xp = jnp.pad(
-        x,
-        ((0, _round_up(m, bm) - m), (0, _round_up(k, kb) - k)),
-        constant_values=-1.0,  # negative pad -> bit 0
-    )
+    xp, bm, bkw = _pad_for_pack(x)
     out = pack_sign_pallas(xp, bm=bm, bkw=bkw, interpret=interpret)
-    return out[:m, :kw]
+    return out[:m, :bitpack.packed_width(k)]
 
 
-@functools.partial(jax.jit, static_argnames=("a_bits", "bm", "bkw", "fused",
+@functools.partial(jax.jit, static_argnames=("a_bits", "fused",
                                              "interpret"))
 def pack_act_planes(
     x: jax.Array,
     a_bits: int,
     *,
-    bm: int = 8,
-    bkw: int = 8,
     fused: bool = True,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -1637,23 +1670,17 @@ def pack_act_planes(
     in ONE fused Pallas pass (quantize -> plane-pack -> row-sum; the k-bit
     analogue of :func:`pack_activations`).  ``fused=False`` is the jnp
     reference round trip (``quant.act_codes`` -> ``bitpack.pack_planes``),
-    bit-identical by construction — the fused kernel calls the same
-    ``quant.act_codes`` on each tile."""
+    bit-identical by construction — the fused kernel runs the same
+    ``quant.act_levels`` arithmetic in fp32 on each tile."""
     m, k = x.shape
-    kw = bitpack.packed_width(k)
     if not fused:
-        codes = quant.act_codes(x, a_bits)  # (M, K) uint32
+        codes = quant.act_codes(x.astype(jnp.float32), a_bits)  # (M, K)
         return (bitpack.pack_planes(codes, a_bits),
                 codes.astype(jnp.int32).sum(axis=-1, keepdims=True))
-    kb = bkw * WORD_BITS
-    xp = jnp.pad(
-        x,
-        ((0, _round_up(m, bm) - m), (0, _round_up(k, kb) - k)),
-        constant_values=-1.0,  # negative pad -> code 0 -> all plane bits 0
-    )
+    xp, bm, bkw = _pad_for_pack(x)
     planes, t_sum = quant_pack_planes_pallas(xp, a_bits, bm=bm, bkw=bkw,
                                              interpret=interpret)
-    return planes[:, :m, :kw], t_sum[:m]
+    return planes[:, :m, :bitpack.packed_width(k)], t_sum[:m]
 
 
 # ---------------------------------------------------------------------------

@@ -40,10 +40,9 @@ int32 accumulator bound: ``S <= K * Na * Nw``, and the dequant numerator
 ``2S - Nw*T`` doubles it — dispatch rejects ``2 * K * Na * Nw >= 2^31``
 at trace time (w8a8: K < ~16.5k; w4a4: K < ~4.7M).  The bound's *shape*
 differs between the two k-bit executions even though the ceiling is the
-same number: THIS kernel accumulates each plane-pair popcount pass
-separately (each pass sums at most K ones; the ``2^(i+j)`` weights are
-applied to the finished pass), so no intermediate ever exceeds the final
-S — whereas the int8 code-lane MXU path (kernels/kbit_mxu.py, the
+same number: THIS kernel adds non-negative ``2^(i+j)``-weighted
+plane-pair popcounts, so no intermediate ever exceeds the final S —
+whereas the int8 code-lane MXU path (kernels/kbit_mxu.py, the
 ``mxu-k*`` backends) accumulates the FULL code dot ``<= K * Na * Nw`` in
 ONE int32 partial per output element.  Dispatch therefore re-derives the
 check per family (``_check_kbit_accumulator`` vs
@@ -63,51 +62,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+from repro.kernels.xnor_gemm import DEFAULT_CHUNK_WORDS, popcount_accumulate
+
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BKW = 16  # words: 16 * 32 = 512 binary values per plane per K-step
 
 
-def _plane_popcount(a_ref, b_ref, out_shape, chunk_words, a_idx=None,
-                    b_idx=None):
-    """Accumulate 2^(i+j)-weighted AND popcounts over every plane pair of
-    one K-block.  ``a_idx``/``b_idx`` prefix-index the expert dim of the
-    batched refs (None for the 2D kernel)."""
-    ka = a_ref.shape[1] if a_idx is not None else a_ref.shape[0]
-    kb = b_ref.shape[1] if b_idx is not None else b_ref.shape[0]
-    bkw = a_ref.shape[-1]
-    n_chunks = bkw // chunk_words
-
-    acc = jnp.zeros(out_shape, jnp.int32)
-    for i in range(ka):
-        for j in range(kb):
-
-            def body(c, pacc, i=i, j=j):
-                sl = pl.ds(c * chunk_words, chunk_words)
-                a = (a_ref[a_idx, i, :, sl] if a_idx is not None
-                     else a_ref[i, :, sl])  # (bm, cw)
-                b = (b_ref[b_idx, j, :, sl] if b_idx is not None
-                     else b_ref[j, :, sl])  # (bn, cw)
-                x = a[:, None, :] & b[None, :, :]  # (bm, bn, cw)
-                pc = jax.lax.population_count(x).astype(jnp.int32).sum(-1)
-                return pacc + pc
-
-            pc = jax.lax.fori_loop(
-                0, n_chunks, body, jnp.zeros(out_shape, jnp.int32)
-            )
-            acc = acc + (1 << (i + j)) * pc
-    return acc
+def _plane_pairs(ka: int, kb: int):
+    return tuple(((i,), (j,), 1 << (i + j))
+                 for i in range(ka) for j in range(kb))
 
 
 def _kbit_kernel(a_ref, b_ref, out_ref, *, chunk_words: int):
     """One (bm, bn) tile: weighted plane popcounts over this K-block."""
-    k_step = pl.program_id(2)
 
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += _plane_popcount(a_ref, b_ref, out_ref.shape, chunk_words)
+    popcount_accumulate(out_ref, a_ref, b_ref,
+                        _plane_pairs(a_ref.shape[0], b_ref.shape[0]),
+                        op=jnp.bitwise_and, chunk_words=chunk_words)
 
 
 def _grid_call(kernel, a_planes, b_planes, bm, bn, bkw, interpret):
@@ -128,7 +105,7 @@ def _grid_call(kernel, a_planes, b_planes, bm, bn, bkw, interpret):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_planes, b_planes)
 
 
@@ -142,8 +119,8 @@ def kbit_plane_gemm_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    chunk_words: int = 8,
-    interpret: bool = True,
+    chunk_words: int = DEFAULT_CHUNK_WORDS,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Weighted bit-plane AND popcount GEMM: returns S (M, N) int32."""
     kernel = functools.partial(_kbit_kernel, chunk_words=chunk_words)
@@ -158,15 +135,15 @@ def kbit_plane_gemm_pallas(
 
 def _kbit_kernel_batched(a_ref, b_ref, out_ref, *, chunk_words: int):
     """One (1, bm, bn) tile of one expert."""
-    k_step = pl.program_id(3)
 
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0, :, :] += _plane_popcount(
-        a_ref, b_ref, out_ref.shape[1:], chunk_words, a_idx=0, b_idx=0
-    )
+    popcount_accumulate(out_ref, a_ref, b_ref,
+                        _plane_pairs(a_ref.shape[1], b_ref.shape[1]),
+                        op=jnp.bitwise_and, chunk_words=chunk_words,
+                        lead=(0,))
 
 
 def _grid_call_batched(kernel, a_planes, b_planes, bm, bn, bkw, interpret):
@@ -187,7 +164,7 @@ def _grid_call_batched(kernel, a_planes, b_planes, bm, bn, bkw, interpret):
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_planes, b_planes)
 
 
@@ -201,8 +178,8 @@ def kbit_plane_gemm_batched_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    chunk_words: int = 8,
-    interpret: bool = True,
+    chunk_words: int = DEFAULT_CHUNK_WORDS,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Expert-batched weighted plane popcount: (E, M, N) int32 S."""
     kernel = functools.partial(_kbit_kernel_batched, chunk_words=chunk_words)

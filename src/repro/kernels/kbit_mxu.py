@@ -16,10 +16,9 @@ accumulation — one 128x128 MAC pass instead of ``ka*kb`` popcount sweeps.
 That is the same re-planning xnor_gemm.py's MXU path applies to the 1-bit
 operands, generalized to bit planes; break-even vs the popcount path is at
 ``ka*kb ~ 16`` (w4a4), and w8a8 is a clear win (benchmarks/roofline.py
-models both).  One carve-out: the unpack cost is M-independent while the
-popcount path scales with M, so at batch M=1 popcount does strictly less
-element work and keeps single-request decode on hosts that time element
-ops (the interpret rig); from M=8 up the MXU path wins outright.
+models both).  The unpack cost is M-independent while the popcount path
+scales with M, so at small decode M popcount does less element work;
+where the two cross on the chip is not measured yet.
 
 int8 range: a k-bit code spans ``[0, 2^k - 1]``, which for k=8 overflows
 int8.  The kernel therefore contracts the *offset* codes ``a_s = n_a -
@@ -69,73 +68,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.bitpack import WORD_BITS
+from repro.kernels import resolve_interpret
+from repro.kernels.xnor_gemm import INT8_DOT
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BKW = 16  # words: 16 * 32 = 512 code lanes per K-step
 
 
-def _unpack_codes_i8(planes: jax.Array, offset: int) -> jax.Array:
-    """(k, rows, kw) uint32 plane words -> (rows, kw*32) int8 offset codes.
+def _codes_i8(planes: jax.Array, s: int, offset: int) -> jax.Array:
+    """Bit position ``s`` of a (k, rows, kw) uint32 plane-word block ->
+    (rows, kw) int8 offset codes ``sum_i 2^i b_i - offset``.
 
-    Reassembles ``n = sum_i 2^i b_i`` per lane and subtracts ``offset``
-    (``2^(k-1)``) so the result fits int8 for every k <= 8.  Zero pad
-    words come out as ``-offset`` — see the module docstring for why that
-    still contributes exactly 0 to the restored S.
-
-    The whole reassembly runs in the uint8 domain: words bitcast to bytes
-    (low byte = lanes 0..7), bits extracted and plane-weighted with uint8
-    shift/mask ops (``((byte >> s) << i) & (1 << i)`` — the stray high
-    bits the left shift drags along are masked off), and accumulated in
-    uint8, which cannot wrap since ``sum_i 2^i <= 255``.  That keeps the
-    unpack — the VPU-side cost this backend pays before its single MXU
-    pass, and the fixed per-tile cost at decode M — in the narrowest
-    lanes: 4x the VPU element density and a quarter the VMEM traffic of
-    an int32-domain unpack.  The final ``- offset`` wraps mod 256, which
-    IS two's-complement int8 subtraction, so the bitcast to int8 lands
-    the exact signed offset code.
-
-    Two trace-time-selected forms of the same arithmetic: wide operands
-    (the weight block, clamped-bm activations at prefill M) run a
-    per-plane loop — a chain XLA fuses well, throughput-bound; skinny
-    operands (the bm <= 4 decode activation rows, where each per-plane
-    op touches a few hundred bytes and per-op dispatch IS the cost) fold
-    the plane axis into one broadcast shift/mask/reduce bundle instead
-    of ``k`` chained ones.
-    """
-    k, rows, kw = planes.shape
-    bytes_ = jax.lax.bitcast_convert_type(planes, jnp.uint8)  # (k,rows,kw,4)
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    if rows <= 4:
-        pw = jnp.arange(k, dtype=jnp.uint8)[:, None, None, None, None]
-        # (k, rows, kw, 4, 8): bit s of plane i, already scaled by 2^i
-        t = ((bytes_[..., None] >> shifts) << pw) & (jnp.uint8(1) << pw)
-        acc = t.sum(axis=0, dtype=jnp.uint8)
-    else:
-        acc = None
-        for i in range(k):
-            t = ((bytes_[i][..., None] >> shifts) << jnp.uint8(i)) & jnp.uint8(
-                1 << i
-            )  # (rows, kw, 4, 8): bit s of plane i, already scaled by 2^i
-            acc = t if acc is None else acc + t
-    acc = (acc - jnp.uint8(offset)).reshape(rows, kw * WORD_BITS)
-    return jax.lax.bitcast_convert_type(acc, jnp.int8)
+    ``offset = 2^(k-1)`` makes the result fit int8 for every k <= 8.  Zero
+    pad words come out as ``-offset`` — see the module docstring for why
+    that still contributes exactly 0 to the restored S.  The reassembly
+    runs in 32-bit lanes, one bit position at a time, so no uint8 bitcast
+    or lane reshape is needed, and narrows once for the MXU."""
+    code = ((planes[0] >> s) & 1).astype(jnp.int32)
+    for i in range(1, planes.shape[0]):
+        code = code | (((planes[i] >> s) & 1).astype(jnp.int32) << i)
+    return (code - offset).astype(jnp.int8)
 
 
 def _offset_dot(a_planes, b_planes):
     """The offset-code dot for one K-block: (bm, bn) int32 from (ka, bm,
-    bkw)/(kb, bn, bkw) uint32 VMEM blocks.  One MXU contraction, no
-    corrections — the rank-1 restore happens once on the grid output."""
-    ka = a_planes.shape[0]
-    kb = b_planes.shape[0]
-    a = _unpack_codes_i8(a_planes, 1 << (ka - 1))  # (bm, bk) int8
-    b = _unpack_codes_i8(b_planes, 1 << (kb - 1))  # (bn, bk) int8
-    return jax.lax.dot_general(
-        a,
-        b,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    bkw)/(kb, bn, bkw) uint32 VMEM blocks.  One int8 MXU contraction per
+    bit position (the dot sums over K lanes, so contracting lane ``s`` of
+    every word at once is the same sum in another order); no corrections
+    — the rank-1 restore happens once on the grid output."""
+    off_a = 1 << (a_planes.shape[0] - 1)
+    off_b = 1 << (b_planes.shape[0] - 1)
+    acc = None
+    for s in range(WORD_BITS):
+        d = jax.lax.dot_general(
+            _codes_i8(a_planes, s, off_a), _codes_i8(b_planes, s, off_b),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=INT8_DOT,
+            preferred_element_type=jnp.int32,
+        )
+        acc = d if acc is None else acc + d
+    return acc
 
 
 def _offset_rowsum(planes: jax.Array, offset: int) -> jax.Array:
@@ -197,7 +170,7 @@ def _grid_call(kernel, a_planes, b_planes, bm, bn, bkw, interpret):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_planes, b_planes)
 
 
@@ -209,7 +182,7 @@ def kbit_mxu_gemm_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """int8 code-lane MXU GEMM: returns the same S (M, N) int32 as
     kbit_plane_gemm_pallas, bit-identically (integer arithmetic only)."""
@@ -253,7 +226,7 @@ def _grid_call_batched(kernel, a_planes, b_planes, bm, bn, bkw, interpret):
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_planes, b_planes)
 
 
@@ -265,7 +238,7 @@ def kbit_mxu_gemm_batched_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Expert-batched int8 code-lane MXU GEMM: (E, M, N) int32 S."""
     dot = _grid_call_batched(_mxu_kbit_kernel_batched, a_planes, b_planes,

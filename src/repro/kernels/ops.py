@@ -13,8 +13,8 @@ Backend selection (see the dispatch registry):
              dry-run lowers, since pallas_call in interpret mode is not a
              meaningful target for cost analysis)
 
-On this CPU container Pallas runs in interpret mode; on a real TPU set
-``interpret=False`` (dispatch reads REPRO_PALLAS_INTERPRET).
+Pallas kernels compile on TPU and interpret on the CPU backend
+(:func:`repro.kernels.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -29,17 +29,14 @@ WORD_BITS = bitpack.WORD_BITS
 
 
 def pack_activations(
-    x: jax.Array, *, bm: int = 8, bkw: int = 8, backend: str = "pallas",
-    interpret: bool | None = None
+    x: jax.Array, *, backend: str = "pallas", interpret: bool | None = None
 ) -> jax.Array:
     """Binarize+pack (M, K) float -> (M, ceil(K/32)) uint32.
 
     Rows are NOT padded (output keeps M); K tail bits are 0.
-    ``interpret=None`` reads REPRO_PALLAS_INTERPRET (dispatch threads
-    ``GemmConfig.interpret`` through the prologue on the layer path).
     """
     return dispatch.pack_activations(
-        x, bm=bm, bkw=bkw, use_pallas=backend != "xla", interpret=interpret
+        x, use_pallas=backend != "xla", interpret=interpret
     )
 
 

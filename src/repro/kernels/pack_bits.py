@@ -8,12 +8,12 @@ data path instead of running it as separate HBM round-trips — the same
 argument applies here, so every member of the family is ONE VMEM pass:
 
 ``pack_sign_pallas``
-    1-bit: read a (bm, bkw*32) float tile, emit a (bm, bkw) uint32 tile of
+    1-bit: read a (bm, 32, bkw) float tile, emit a (bm, bkw) uint32 tile of
     sign bits (x >= 0 -> bit 1, the core/bitpack.py convention).
 
 ``quant_pack_planes_pallas``
     k-bit (DoReFa Eq. 1): read the same float tile, quantize to integer
-    codes via ``quant.act_codes`` (clip to [0, 1], scale, round — called
+    codes via ``quant.act_levels`` (clip to [0, 1], scale, round — called
     directly so the kernel CANNOT drift from the fake-quant train path),
     split into ``a_bits`` bit planes and word-pack each, emitting a
     (a_bits, bm, bkw) plane-stack tile PLUS the int32 code row-sums T the
@@ -25,10 +25,12 @@ Both kernels require pre-padded inputs (M to bm, K to bkw*32); pad floats
 with a NEGATIVE value so pad bits are 0 (1-bit) / code 0 (k-bit) — zero in
 both GEMM operands, contributing nothing (see core/bitpack.py).
 
-``interpret=None`` reads REPRO_PALLAS_INTERPRET like the GEMM kernels —
-callers thread ``GemmConfig.interpret`` through ``kernels/dispatch`` so a
-real-TPU config compiles the pack stage too instead of silently
-interpreting it.
+Word-major view: the wrappers hand the kernels ``x`` as (M, 32, K/32) —
+bit ``s`` of word ``w`` on sublane ``s``, lane ``w`` — so each of the 32
+bit positions is one sublane row of the tile and every block is a legal
+Mosaic tile: (bm, 32, bkw) in, (bm, bkw) words out, with bkw a multiple
+of 128 or the whole padded word count.  The transpose into that view is
+one XLA copy of the activations ahead of the kernel.
 
 One plane stack serves BOTH k-bit GEMM families — the ``vpu-k*`` plane
 popcount kernels and the ``mxu-k*`` int8 code-lane kernels
@@ -45,7 +47,6 @@ that used to separate one layer's reduction from the next layer's pack
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -53,56 +54,56 @@ from jax.experimental import pallas as pl
 
 from repro.core import quant
 from repro.core.bitpack import WORD_BITS
-
-DEFAULT_BM = 256
-DEFAULT_BKW = 32  # words per block: 32 * 32 = 1024 floats per row-block
+from repro.kernels import resolve_interpret
 
 
-def _env_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
+def _word_major(x: jax.Array) -> jax.Array:
+    """(M, K) -> (M, 32, K/32): element 32w+s lands at [:, s, w]."""
+    m, k = x.shape
+    return x.reshape(m, k // WORD_BITS, WORD_BITS).swapaxes(1, 2)
 
 
-def _resolve_interpret(interpret: bool | None) -> bool:
-    return interpret if interpret is not None else _env_interpret()
-
-
-def _pack_words(bits: jax.Array) -> jax.Array:
-    """(bm, n_words, 32) {0,1} uint32 -> (bm, n_words) packed words."""
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    return (bits << shifts).sum(axis=-1, dtype=jnp.uint32)
+def _pack_words(bit) -> jax.Array:
+    """``bit(s) -> (bm, bkw) int32 {0,1}`` for s in 0..31 -> (bm, bkw)
+    uint32 words (bit s of the word = element 32w+s)."""
+    acc = bit(0)
+    for s in range(1, WORD_BITS):
+        acc = acc | (bit(s) << s)
+    return jax.lax.bitcast_convert_type(acc, jnp.uint32)
 
 
 def _pack_kernel(x_ref, out_ref):
-    x = x_ref[...]  # (bm, bkw * 32) float
-    bm, kbits = x.shape
-    bits = (x >= 0).astype(jnp.uint32).reshape(bm, kbits // WORD_BITS,
-                                               WORD_BITS)
-    out_ref[...] = _pack_words(bits)
+    out_ref[...] = _pack_words(
+        lambda s: (x_ref[:, s, :].astype(jnp.float32) >= 0).astype(jnp.int32))
+
+
+def _check_tiles(m, k, bm, bkw):
+    kb = bkw * WORD_BITS
+    assert m % bm == 0 and k % kb == 0, (m, bm, k, kb)
+    return kb
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bkw", "interpret"))
 def pack_sign_pallas(
     x: jax.Array,  # (M, K) float; M % bm == 0, K % (bkw*32) == 0 (pre-padded)
     *,
-    bm: int = DEFAULT_BM,
-    bkw: int = DEFAULT_BKW,
+    bm: int,
+    bkw: int,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Returns (M, K/32) uint32.  Pad K with negative values (bit 0) first;
     dispatch.pack_activations handles the padding so pad bits are 0 in both
     GEMM operands."""
     m, k = x.shape
-    kb = bkw * WORD_BITS
-    assert m % bm == 0 and k % kb == 0, (m, bm, k, kb)
-    grid = (m // bm, k // kb)
+    _check_tiles(m, k, bm, bkw)
     return pl.pallas_call(
         _pack_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((bm, kb), lambda i, j: (i, j))],
+        grid=(m // bm, k // (bkw * WORD_BITS)),
+        in_specs=[pl.BlockSpec((bm, WORD_BITS, bkw), lambda i, j: (i, 0, j))],
         out_specs=pl.BlockSpec((bm, bkw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k // WORD_BITS), jnp.uint32),
-        interpret=_resolve_interpret(interpret),
-    )(x)
+        interpret=resolve_interpret(interpret),
+    )(_word_major(x))
 
 
 # ---------------------------------------------------------------------------
@@ -111,22 +112,19 @@ def pack_sign_pallas(
 
 
 def _quant_pack_planes_kernel(x_ref, planes_ref, tsum_ref, *, a_bits: int):
-    """One (bm, bkw*32) float tile -> (a_bits, bm, bkw) plane words and the
+    """One (bm, 32, bkw) float tile -> (a_bits, bm, bkw) plane words and the
     running int32 code row-sums (accumulated over the sequential K axis)."""
-    x = x_ref[...]  # (bm, bkw * 32) float
-    bm, kbits = x.shape
-    codes = quant.act_codes(x, a_bits)  # (bm, kbits) uint32 — Eq. 1 codes
-    cw = codes.reshape(bm, kbits // WORD_BITS, WORD_BITS)
+    codes = [quant.act_levels(x_ref[:, s, :].astype(jnp.float32),
+                              a_bits).astype(jnp.int32)
+             for s in range(WORD_BITS)]  # 32 x (bm, bkw) Eq. 1 codes
     for i in range(a_bits):
-        planes_ref[i, :, :] = _pack_words((cw >> jnp.uint32(i)) & jnp.uint32(1))
+        planes_ref[i, :, :] = _pack_words(lambda s: (codes[s] >> i) & 1)
 
-    k_step = pl.program_id(1)
-
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         tsum_ref[...] = jnp.zeros_like(tsum_ref)
 
-    tsum_ref[...] += codes.astype(jnp.int32).sum(axis=-1, keepdims=True)
+    tsum_ref[...] += sum(codes).sum(axis=-1, keepdims=True)
 
 
 @functools.partial(
@@ -136,8 +134,8 @@ def quant_pack_planes_pallas(
     x: jax.Array,  # (M, K) float, pre-padded (M % bm == 0, K % (bkw*32) == 0)
     a_bits: int,
     *,
-    bm: int = DEFAULT_BM,
-    bkw: int = DEFAULT_BKW,
+    bm: int,
+    bkw: int,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused DoReFa activation prologue: quantize (clip -> codes) and
@@ -149,15 +147,15 @@ def quant_pack_planes_pallas(
     0) so pad bits are 0 in every plane and contribute 0 to both the plane
     GEMM and T."""
     m, k = x.shape
-    kb = bkw * WORD_BITS
-    assert m % bm == 0 and k % kb == 0, (m, bm, k, kb)
+    _check_tiles(m, k, bm, bkw)
     assert 2 <= a_bits <= 8, a_bits
-    grid = (m // bm, k // kb)  # K innermost: sequential row-sum accumulation
+    # K innermost: sequential row-sum accumulation
+    grid = (m // bm, k // (bkw * WORD_BITS))
     kernel = functools.partial(_quant_pack_planes_kernel, a_bits=a_bits)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((bm, kb), lambda i, j: (i, j))],
+        in_specs=[pl.BlockSpec((bm, WORD_BITS, bkw), lambda i, j: (i, 0, j))],
         out_specs=[
             pl.BlockSpec((a_bits, bm, bkw), lambda i, j: (0, i, j)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
@@ -166,5 +164,5 @@ def quant_pack_planes_pallas(
             jax.ShapeDtypeStruct((a_bits, m, k // WORD_BITS), jnp.uint32),
             jax.ShapeDtypeStruct((m, 1), jnp.int32),
         ],
-        interpret=_resolve_interpret(interpret),
-    )(x)
+        interpret=resolve_interpret(interpret),
+    )(_word_major(x))

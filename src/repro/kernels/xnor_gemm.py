@@ -14,11 +14,12 @@ values per word, packed along K — see core/bitpack.py):
 ``xnor_gemm_mxu``
     TPU-native beyond-paper variant: stream the *packed* words HBM->VMEM
     (32x less traffic than bf16 — the part of the paper's insight that
-    matters on TPU), unpack to ±1 int8 *in VMEM*, and contract on the MXU
-    with int32 accumulation.  The MXU runs 128x128 MACs/cycle, so once the
-    bytes are on-chip it beats lane-wise popcount by a large factor; the
-    popcount trick mattered on CPUs because *there* the ALU was the
-    bottleneck.  Padding bits unpack to (-1,-1) pairs and inflate the dot by
+    matters on TPU), unpack to ±1 int8 *in VMEM* one bit position at a
+    time, and contract on the MXU with int32 accumulation.  The MXU runs
+    128x128 MACs/cycle, so once the bytes are on-chip it should beat
+    lane-wise popcount; the popcount trick mattered on CPUs because
+    *there* the ALU was the bottleneck (which wins on the chip is not
+    measured yet).  Padding bits unpack to (-1,-1) pairs and inflate the dot by
     ``pad = Kw*32 - k_true``; callers subtract it (ops.py does).
 
 Both kernels tile (M, N, K) with a sequential-K innermost grid axis and an
@@ -41,10 +42,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.bitpack import WORD_BITS
+from repro.kernels import LANES, SUBLANES, resolve_interpret
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BKW = 64  # words: 64 * 32 = 2048 binary values per K-step
+DEFAULT_CHUNK_WORDS = LANES  # one lane tile of words
+# int8 MXU dots are exact at any precision, and Mosaic refuses an fp32
+# contract precision on int8 operands: pin it, so a caller's
+# default_matmul_precision("highest") cannot reach these dots
+INT8_DOT = jax.lax.Precision.DEFAULT
 
 
 def mxu_pad_inflation(total_words: int, k_true: int) -> int:
@@ -59,56 +66,97 @@ def mxu_pad_inflation(total_words: int, k_true: int) -> int:
     return total_words * WORD_BITS - k_true
 
 
+def popcount_accumulate(out_ref, a_ref, b_ref, pairs, *, op, chunk_words,
+                        lead=()):
+    """``out_ref[lead] (bm, bn) int32 += sum_(i, j, wt) wt * sum_w
+    popcount(op(a_ref[lead + i][r, w], b_ref[lead + j][c, w]))`` over one
+    K-block.
+
+    ``pairs`` lists ``(i, j, weight)`` with ``i``/``j`` tuples of leading
+    indices (a plane of a k-bit stack, or ``()``) after the common
+    ``lead`` (the expert of a batched block).  Refs are only ever indexed
+    with integers and aligned slices — Mosaic refuses a sliced ref view.
+    Every slice is tile-aligned for Mosaic: lane chunks of ``chunk_words``
+    words (a multiple of 128, or the whole block) at multiple-of-chunk
+    offsets, and row groups of 8 sublanes (or the whole block), which also
+    bounds the (rows, bn, chunk) broadcast intermediate.  Terms are non-negative, so
+    no partial sum exceeds the final one (the int32 bound dispatch checks
+    covers every intermediate)."""
+    bm = out_ref.shape[-2]
+    bkw = a_ref.shape[-1]
+    chunk_words = min(chunk_words, bkw)
+    assert bkw % chunk_words == 0, (bkw, chunk_words)
+    rg = SUBLANES if bm % SUBLANES == 0 else bm
+    n_chunks = bkw // chunk_words
+
+    def span(i, size, total):  # a whole ref dim is sliced statically
+        if size == total:
+            return slice(None)
+        return pl.ds(pl.multiple_of(i * size, size), size)
+
+    def group(r, carry):
+        rs = span(r, rg, bm)
+
+        def chunk(c, acc):
+            ls = span(c, chunk_words, bkw)
+            for i, j, wt in pairs:
+                a = a_ref[lead + i + (rs, ls)]  # (rg, cw)
+                b = b_ref[lead + j + (slice(None), ls)]  # (bn, cw)
+                x = op(a[:, None, :], b[None, :, :])  # (rg, bn, cw)
+                pc = jax.lax.population_count(x).astype(jnp.int32).sum(-1)
+                acc = acc + (pc if wt == 1 else wt * pc)
+            return acc
+
+        acc = jax.lax.fori_loop(0, n_chunks, chunk,
+                                jnp.zeros((rg, out_ref.shape[-1]), jnp.int32))
+        out_ref[lead + (rs, slice(None))] += acc
+        return carry
+
+    jax.lax.fori_loop(0, bm // rg, group, 0)
+
+
 def _vpu_kernel(a_ref, b_ref, out_ref, *, chunk_words: int):
     """One (bm, bn) tile: accumulate popcount(xor) over this K-block."""
-    k_step = pl.program_id(2)
 
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bkw = a_ref.shape[1]
-    n_chunks = bkw // chunk_words
-
-    def body(c, acc):
-        sl = pl.ds(c * chunk_words, chunk_words)
-        a = a_ref[:, sl]  # (bm, cw)
-        b = b_ref[:, sl]  # (bn, cw)
-        x = a[:, None, :] ^ b[None, :, :]  # (bm, bn, cw)
-        m = jax.lax.population_count(x).astype(jnp.int32).sum(axis=-1)
-        return acc + m
-
-    acc = jax.lax.fori_loop(
-        0, n_chunks, body, jnp.zeros(out_ref.shape, jnp.int32)
-    )
-    out_ref[...] += acc
+    popcount_accumulate(out_ref, a_ref, b_ref, (((), (), 1),),
+                        op=jnp.bitwise_xor, chunk_words=chunk_words)
 
 
-def _unpack_pm1_i8(words: jax.Array) -> jax.Array:
-    """(rows, kw) uint32 -> (rows, kw*32) int8 in {-1, +1} (bit 1 -> +1)."""
-    rows, kw = words.shape
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    bits = (words[:, :, None] >> shifts) & jnp.uint32(1)  # (rows, kw, 32)
-    pm1 = (2 * bits.astype(jnp.int8) - 1).reshape(rows, kw * WORD_BITS)
-    return pm1
+def _pm1_i8(words: jax.Array, s: int) -> jax.Array:
+    """Bit ``s`` of every word as int8 ±1 (bit 1 -> +1)."""
+    return (((words >> s) & 1).astype(jnp.int32) * 2 - 1).astype(jnp.int8)
+
+
+def mxu_pm1_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """(bm, bkw) x (bn, bkw) packed words -> (bm, bn) int32 ±1 dot over
+    every bit (pad bits included).  One int8 MXU contraction per bit
+    position: the dot is a sum over K lanes, so contracting bit ``s`` of
+    all words at once is the same sum in another order, and no unpacked
+    (rows, bkw*32) lane reshuffle is ever built."""
+    acc = None
+    for s in range(WORD_BITS):
+        d = jax.lax.dot_general(
+            _pm1_i8(a, s), _pm1_i8(b, s),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=INT8_DOT,
+            preferred_element_type=jnp.int32,
+        )
+        acc = d if acc is None else acc + d
+    return acc
 
 
 def _mxu_kernel(a_ref, b_ref, out_ref):
     """One (bm, bn) tile: unpack packed words in VMEM, contract on the MXU."""
-    k_step = pl.program_id(2)
 
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = _unpack_pm1_i8(a_ref[...])  # (bm, bkw*32) int8
-    b = _unpack_pm1_i8(b_ref[...])  # (bn, bkw*32) int8
-    out_ref[...] += jax.lax.dot_general(
-        a,
-        b,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    out_ref[...] += mxu_pm1_dot(a_ref[...], b_ref[...])
 
 
 def _grid_call(kernel, a_packed, b_packed, bm, bn, bkw, interpret):
@@ -129,7 +177,7 @@ def _grid_call(kernel, a_packed, b_packed, bm, bn, bkw, interpret):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_packed, b_packed)
 
 
@@ -143,8 +191,8 @@ def xnor_mismatch_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    chunk_words: int = 8,
-    interpret: bool = True,
+    chunk_words: int = DEFAULT_CHUNK_WORDS,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """VPU popcount path: returns raw xor-mismatch counts (M, N) int32.
 
@@ -162,7 +210,7 @@ def xnor_dot_mxu_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """MXU path: returns the *padded* ±1 dot (M, N) int32.
 
@@ -181,45 +229,24 @@ def xnor_dot_mxu_pallas(
 
 def _vpu_kernel_batched(a_ref, b_ref, out_ref, *, chunk_words: int):
     """One (1, bm, bn) tile of one expert: popcount(xor) over this K-block."""
-    k_step = pl.program_id(3)
 
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bkw = a_ref.shape[-1]
-    n_chunks = bkw // chunk_words
-
-    def body(c, acc):
-        sl = pl.ds(c * chunk_words, chunk_words)
-        a = a_ref[0, :, sl]  # (bm, cw)
-        b = b_ref[0, :, sl]  # (bn, cw)
-        x = a[:, None, :] ^ b[None, :, :]  # (bm, bn, cw)
-        m = jax.lax.population_count(x).astype(jnp.int32).sum(axis=-1)
-        return acc + m
-
-    acc = jax.lax.fori_loop(
-        0, n_chunks, body, jnp.zeros(out_ref.shape[1:], jnp.int32)
-    )
-    out_ref[0, :, :] += acc
+    popcount_accumulate(out_ref, a_ref, b_ref, (((), (), 1),),
+                        op=jnp.bitwise_xor, chunk_words=chunk_words,
+                        lead=(0,))
 
 
 def _mxu_kernel_batched(a_ref, b_ref, out_ref):
     """One (1, bm, bn) tile of one expert: unpack in VMEM, MXU contraction."""
-    k_step = pl.program_id(3)
 
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = _unpack_pm1_i8(a_ref[0])  # (bm, bkw*32) int8
-    b = _unpack_pm1_i8(b_ref[0])  # (bn, bkw*32) int8
-    out_ref[0, :, :] += jax.lax.dot_general(
-        a,
-        b,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    out_ref[0, :, :] += mxu_pm1_dot(a_ref[0], b_ref[0])
 
 
 def _grid_call_batched(kernel, a_packed, b_packed, bm, bn, bkw, interpret):
@@ -240,7 +267,7 @@ def _grid_call_batched(kernel, a_packed, b_packed, bm, bn, bkw, interpret):
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_packed, b_packed)
 
 
@@ -254,8 +281,8 @@ def xnor_mismatch_batched_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    chunk_words: int = 8,
-    interpret: bool = True,
+    chunk_words: int = DEFAULT_CHUNK_WORDS,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Expert-batched VPU popcount path: (E, M, N) int32 mismatch counts."""
     kernel = functools.partial(_vpu_kernel_batched, chunk_words=chunk_words)
@@ -271,7 +298,7 @@ def xnor_dot_mxu_batched_pallas(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bkw: int = DEFAULT_BKW,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Expert-batched MXU path: (E, M, N) int32 *padded* dots (see 2D doc)."""
     return _grid_call_batched(_mxu_kernel_batched, a_packed, b_packed,

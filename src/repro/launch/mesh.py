@@ -10,20 +10,33 @@ plain CPU).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """THE mesh constructor: every mesh in the repo comes from here.
+
+    Axes are ``AxisType.Auto`` — GSPMD propagates shardings through the
+    model, as the code was written for; ``jax.make_mesh`` alone defaults
+    to ``Explicit`` axes, under which the embedding gather and the
+    sharded cache updates refuse to trace."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 v5e chips) or 2x16x16 (2 pods, 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this process actually has — used by smoke training runs.
     data axis = all local devices, model axis = 1."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def make_elastic_mesh(model_parallel: int = 16):
@@ -35,4 +48,4 @@ def make_elastic_mesh(model_parallel: int = 16):
     mp = min(model_parallel, n)
     while n % mp:
         mp -= 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))

@@ -59,6 +59,8 @@ import numpy as np
 
 from repro.core import converter
 from repro.launch import cli
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.train import parse_quant
 from repro.models import lm as lm_model
 from repro.models import registry
@@ -125,6 +127,7 @@ def main() -> None:
                          "the Scheduler queue (slots recycle as requests "
                          "finish) instead of one rectangular batch")
     args = ap.parse_args()
+    use_compile_cache()
 
     spec = registry.get(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
@@ -132,7 +135,7 @@ def main() -> None:
     mesh = None
     if args.gemm_backend.startswith("shard-"):
         ways = args.shard or len(jax.devices())
-        mesh = jax.make_mesh((ways,), ("model",))
+        mesh = make_mesh((ways,), ("model",))
         print(f"tensor-parallel packed GEMM: {ways}-way "
               f"(layout {args.shard_layout!r})")
     ctx = QCtx(policy=policy, compute_dtype=jnp.float32, mesh=mesh,

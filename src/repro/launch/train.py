@@ -4,6 +4,8 @@ Runs on whatever devices exist (1 CPU here; the production mesh on a real
 cluster).  Supports:
 
   --arch <id> --smoke            reduced config (CPU-trainable)
+  --layers N                     published widths cut to N layers (one
+                                 chip's share of a deep model)
   --quant fp|binary|w2a2|...     BMXNet policy for every internal GEMM
   --resume auto                  restart from the latest valid checkpoint
   --grad-compress                sharded DP train step with the 1-bit EF
@@ -27,7 +29,9 @@ Example (the quickstart driver):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
+import math
 import time
 
 import jax
@@ -38,6 +42,7 @@ from repro.ckpt.manager import CheckpointManager, export_packed
 from repro.core.policy import PolicySchedule, QuantPolicy
 from repro.data import synthetic
 from repro.dist.sharding import Resolver
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import registry
 from repro.nn.common import QCtx
@@ -71,10 +76,13 @@ def batch_fn_for(spec, cfg, dcfg):
     return lambda step: synthetic.batch_at(dcfg, step)
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Train; returns the last logged step's metrics."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the config's depth to this many layers")
     ap.add_argument("--quant", default="fp")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=16)
@@ -97,10 +105,13 @@ def main() -> None:
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     spec = registry.get(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     policy = parse_quant(args.quant)
     if args.two_stage:
         if policy.w_bits != 1:
@@ -193,6 +204,7 @@ def main() -> None:
     pf = synthetic.Prefetcher(batch_fn_for(spec, cfg, dcfg), start)
     t0 = time.time()
     t_last, i_last = t0, start
+    m = {"loss": math.nan}
     try:
         with mesh:
             for i in range(start, args.steps):
@@ -234,6 +246,7 @@ def main() -> None:
             json.dump({"fp32_bytes": report.bytes_fp32,
                        "packed_bytes": report.bytes_after,
                        "ratio": report.ratio}, f)
+    return m
 
 
 if __name__ == "__main__":

@@ -517,11 +517,12 @@ class ContiguousKVCache(KVCache):
 class PagedKVCache(KVCache):
     """Block-table paged storage over a shared pool.
 
-    Leaves: ``pool_k``/``pool_v`` (num_blocks, block_size, KVH, Dh)
-    (quantized tiers store codes here plus scale pools ``pool_ks``/
-    ``pool_vs`` riding the same flat-index scatters — base-class
-    docstring), ``pool_pos`` (num_blocks, block_size) int32 absolute
-    token positions
+    Leaves: ``pool_k``/``pool_v`` (num_blocks, KVH, block_size, Dh) —
+    heads outside the token rows, the order the fused kernel DMAs
+    (kernels/attn_decode.py) — (quantized tiers store codes here plus
+    scale pools ``pool_ks``/``pool_vs`` (num_blocks, KVH, block_size,
+    groups) riding the same scatters — base-class docstring),
+    ``pool_pos`` (num_blocks, block_size) int32 absolute token positions
     (-1 = empty), ``table`` (B, blocks_per_slot) int32 block ids (-1 =
     unmapped — the whole slot is invisible).  Token at slot-local position
     ``p`` lives in block ``table[b, p // block_size]`` at offset
@@ -558,19 +559,17 @@ class PagedKVCache(KVCache):
         return {"pool_k": kc, "pool_ks": ks, "pool_v": vc, "pool_vs": vs}
 
     def _scatter(self, cache, flat, enc, positions):
-        """Scatter encoded (B, S, ...) leaves + positions to flattened
+        """Scatter encoded (B, S, KVH, ...) leaves + positions to flattened
         pool indices ``flat`` ((B*S,); invalid -> nb*bs, mode='drop')."""
         nb, bs = cache["pool_pos"].shape
         n = flat.shape[0]
+        blk, off = flat // bs, flat % bs  # invalid -> blk nb: dropped
         out = dict(cache)
         for name, val in enc.items():
-            pool = cache[name]
-            out[name] = (
-                pool.reshape((nb * bs,) + pool.shape[2:])
-                .at[flat].set(
-                    val.astype(pool.dtype).reshape((n,) + pool.shape[2:]),
-                    mode="drop")
-                .reshape(pool.shape))
+            pool = cache[name]  # (nb, KVH, bs, ...)
+            rows = val.astype(pool.dtype).reshape(
+                (n, pool.shape[1]) + pool.shape[3:])
+            out[name] = pool.at[blk, :, off].set(rows, mode="drop")
         out["pool_pos"] = (cache["pool_pos"].reshape(nb * bs)
                            .at[flat].set(positions.reshape(-1), mode="drop")
                            .reshape(nb, bs))
@@ -600,15 +599,18 @@ class PagedKVCache(KVCache):
         nb = b * bps  # the contiguous layout's exact footprint
         (code, cdt), sc = kv_code_shapes(self.kv_bits, cfg.n_kv_heads,
                                          cfg.d_head, dtype)
+        def pool(shape, dt):  # per-token (KVH, d) -> (nb, KVH, bs, d)
+            return jnp.zeros((nb, shape[0], bs) + shape[1:], dt)
+
         out = {
-            "pool_k": jnp.zeros((nb, bs) + code, cdt),
-            "pool_v": jnp.zeros((nb, bs) + code, cdt),
+            "pool_k": pool(code, cdt),
+            "pool_v": pool(code, cdt),
             "pool_pos": jnp.full((nb, bs), -1, jnp.int32),
             "table": jnp.full((b, bps), -1, jnp.int32),
         }
         if sc is not None:
-            out["pool_ks"] = jnp.zeros((nb, bs) + sc[0], sc[1])
-            out["pool_vs"] = jnp.zeros((nb, bs) + sc[0], sc[1])
+            out["pool_ks"] = pool(*sc)
+            out["pool_vs"] = pool(*sc)
         return out
 
     def insert(self, cache, sub, slots):
@@ -683,12 +685,16 @@ class PagedKVCache(KVCache):
         b, bps = table.shape
         bs = self.block_size
         safe = jnp.clip(table, 0)
-        k = cache["pool_k"][safe]  # (B, bps, bs, KVH, Dh-coded)
-        v = cache["pool_v"][safe]
+
+        def rows(name):  # (nb, KVH, bs, d) -> (B, bps, bs, KVH, d)
+            return cache[name][safe].swapaxes(2, 3)
+
+        k = rows("pool_k")
+        v = rows("pool_v")
         if self.kv_bits is not None:
             dh_fp = k.shape[-1] * (8 if self.kv_bits == 1 else 1)
-            k = kv_dequantize(self.kv_bits, k, cache["pool_ks"][safe], dh_fp)
-            v = kv_dequantize(self.kv_bits, v, cache["pool_vs"][safe], dh_fp)
+            k = kv_dequantize(self.kv_bits, k, rows("pool_ks"), dh_fp)
+            v = kv_dequantize(self.kv_bits, v, rows("pool_vs"), dh_fp)
         pos = jnp.where(table[:, :, None] >= 0, cache["pool_pos"][safe], -1)
         kvh, dh = k.shape[-2:]
         return (k.reshape(b, bps * bs, kvh, dh),

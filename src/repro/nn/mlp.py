@@ -263,7 +263,7 @@ def moe_apply(
             return jax.lax.psum(y_part, "model")
 
         dspec = P(dp if dp else None)
-        from repro.compat import shard_map
+        from jax import shard_map
 
         y = shard_map(
             local, mesh=mesh,

@@ -16,9 +16,9 @@ Two step factories share one gradient-accumulation core:
   step over :class:`TrainState`: the whole step runs inside ``shard_map``,
   each data-parallel member computes gradients on its batch shard, and the
   gradient exchange over ``TrainConfig.dp_axis`` is either the plain
-  ``psum`` mean (the CI-gated oracle — bit-identical to the single-device
-  step with ``microbatch=dp``, because XLA's psum reduces members in ring
-  order exactly like the microbatch scan's left fold) or the 1-bit
+  ``psum`` mean (the CI-gated oracle — equal to the single-device step
+  with ``microbatch=dp`` to fp32 rounding: XLA's psum sums the members in
+  its own order, not the microbatch scan's left fold) or the 1-bit
   error-feedback collective ``dist.compress.compressed_psum`` (the paper's
   ~32x wire shrink, §2.2.3, applied to training traffic).  The EF residual
   is member-local state: :class:`TrainState.ef` leaves carry a leading
@@ -46,7 +46,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.common import ArchSpec
 from repro.core.policy import PolicySchedule, QuantPolicy
 from repro.dist import compress as dist_compress
@@ -166,8 +166,8 @@ def _accumulate_grads(grad_fn, params, batch, microbatch):
     chunks (left-fold scan; ``SUM_AUX_KEYS`` aux entries summed instead).
 
     The single-chunk form is the plain ``grad_fn`` call; the DP step's
-    psum over members continues the same fold (XLA ring order), which is
-    what makes DP(dp) bit-identical to microbatch=dp on one device.
+    psum over members computes the same mean in another summation order,
+    so DP(dp) matches microbatch=dp on one device to fp32 rounding.
     """
     if not microbatch or microbatch <= 1:
         (loss, aux), grads = grad_fn(params, batch)
@@ -365,7 +365,7 @@ def make_sharded_train_step(
     axis.  Inside the ``shard_map`` body each member runs the (optionally
     microbatched) gradient computation on its shard, then the gradient
     mean over the DP axis is either ``lax.psum / dp`` (uncompressed — the
-    bit-identical oracle) or ``dist.compress.compressed_psum`` (1-bit EF).
+    oracle, to fp32 rounding) or ``dist.compress.compressed_psum`` (1-bit EF).
     The returned callable is jit-able (``jax.jit(step, donate_argnums=0)``);
     metrics come out replicated.
 
@@ -406,8 +406,8 @@ def make_sharded_train_step(
             )
             ef = jax.tree.map(lambda x: x[None], e_new)
         else:
-            # psum continues the microbatch scan's left fold in ring
-            # order -> bit-identical to microbatch=dp on one device
+            # the same mean as microbatch=dp on one device, summed in
+            # the collective's order (equal to fp32 rounding)
             grads = jax.tree.map(
                 lambda g: jax.lax.psum(g, tc.dp_axis) / dp, grads
             )

@@ -10,6 +10,7 @@ import numpy as np
 from repro.ckpt.manager import CheckpointManager, export_packed
 from repro.core.policy import QuantPolicy
 from repro.core import qlayers
+from repro.launch.mesh import make_mesh
 
 
 def _tree(seed=0):
@@ -121,7 +122,7 @@ def test_restore_with_shardings(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     tree = _tree()
     mgr.save(3, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(
         lambda _: jax.NamedSharding(mesh, jax.sharding.PartitionSpec()), tree)
     step, got = mgr.restore(tree, shardings=sh)

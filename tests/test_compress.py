@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist import compress
+from repro.launch.mesh import make_mesh
 
 
 def test_compress_leaf_is_sign_times_scale():
@@ -57,11 +58,11 @@ def test_payload_accounting():
 def test_compressed_psum_shard_map():
     """compressed_psum under shard_map on a 1-device 'pod' axis: with a
     single member the mean equals the compressed grad itself."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     grads = {"w": jnp.asarray([[1.0, -2.0], [0.5, -0.5]])}
     ef = compress.ef_init(grads)
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def f(g, e):
         return compress.compressed_psum(g, e, "pod")

@@ -187,6 +187,36 @@ def test_tile_table_covers_and_divides():
             assert t.bm >= min(m, 8) and t.bn >= min(n, 8)
 
 
+def _mosaic_legal(t, m, n, kw):
+    """Mosaic's block rule for the (bm, bkw) / (bn, bkw) / (bm, bn)
+    blocks of operands padded up to the tile."""
+    return ((t.bm >= m or t.bm % 8 == 0)
+            and (t.bn >= n or t.bn % 128 == 0)
+            and (t.bkw >= kw or t.bkw % 128 == 0)
+            and t.bkw % t.chunk_words == 0
+            and (t.chunk_words == t.bkw or t.chunk_words % 128 == 0))
+
+
+@pytest.mark.parametrize("backend", ["vpu", "mxu", "vpu-k4", "mxu-k8"])
+def test_select_tiles_mosaic_legal(backend, monkeypatch):
+    """Every heuristic tile obeys Mosaic's block rule, and a cached winner
+    that does not (measured under the interpreter) is screened before it
+    can reach the compiler."""
+    for m, n, kw in [(1, 2048, 64), (8, 8192, 64), (8, 2048, 256),
+                     (256, 8192, 64), (3, 100, 200), (1000, 7, 1000)]:
+        assert _mosaic_legal(dispatch.select_tiles(m, n, kw, backend),
+                             m, n, kw)
+    monkeypatch.setattr(dispatch, "_TUNED", {
+        (16, 512, 256, backend): dispatch.TileConfig(4, 64, 16, 8)})
+    dispatch.select_tiles.cache_clear()
+    try:
+        t = dispatch.select_tiles(16, 512, 256, backend)
+        assert _mosaic_legal(t, 16, 512, 256)
+        assert t == dispatch.TileConfig(8, 128, 128, 128)
+    finally:
+        dispatch.select_tiles.cache_clear()
+
+
 def test_config_tile_overrides_win():
     cfg = GemmConfig(backend="vpu", bm=16, bkw=8, chunk_words=4)
     t = cfg.tiles(100, 100, 64)

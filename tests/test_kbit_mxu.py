@@ -20,6 +20,7 @@ from hypothesis import strategies as st
 from repro.core import bitpack, quant
 from repro.kernels import dispatch, ref
 from repro.kernels.dispatch import GemmConfig
+from repro.launch.mesh import make_mesh
 
 BITS = [2, 4, 8]
 WAYS = [1, 2, 4, 8]
@@ -190,7 +191,7 @@ def test_shard_mxu_kbit_from_float_property(tail, bits, ways):
     params from pytest.)"""
     if len(jax.devices()) < ways:
         pytest.skip(f"{ways}-way mesh needs virtual host devices")
-    mesh = jax.make_mesh((ways,), ("model",))
+    mesh = make_mesh((ways,), ("model",))
     k = 3 * 32 + tail
     m, n = 5, 6
     a, _, _, wp = _plane_operands(tail * 7 + bits, m, k, n, bits)

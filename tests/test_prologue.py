@@ -120,9 +120,8 @@ def test_prologue_spec_overrides_config():
 
 def test_pack_kernels_honor_interpret_flag(monkeypatch):
     """GemmConfig.interpret must reach the prologue pallas_call like it
-    reaches the GEMM kernels — the env default must NOT win when the
-    config is explicit (the pack kernels used to hard-default to
-    interpret=True)."""
+    reaches the GEMM kernels: an explicit config wins over the platform
+    default (the pack kernels used to hard-default to interpret=True)."""
     seen = {}
     real = dispatch.pack_sign_pallas
 
@@ -131,29 +130,42 @@ def test_pack_kernels_honor_interpret_flag(monkeypatch):
         return real(x, **kw)
 
     monkeypatch.setattr(dispatch, "pack_sign_pallas", spy)
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")  # env says compile
     m, k, n = 3, 51, 4  # unique shape: the jit caches cannot satisfy this
     x = _acts(7, m, k)
     wp = bitpack.pack_sign(_acts(8, n, k))
     got = dispatch.quant_gemm(
         x, wp, k_true=k,
         config=GemmConfig(backend="vpu", interpret=True))
-    assert seen["interpret"] is True  # config won over the env's False
+    assert seen["interpret"] is True
     np.testing.assert_array_equal(
         np.asarray(got),
         np.asarray(ref.xnor_gemm_ref(bitpack.pack_sign(x), wp, k)))
 
 
-def test_pack_sign_pallas_default_reads_env(monkeypatch):
-    """interpret=None resolves REPRO_PALLAS_INTERPRET instead of a
-    hardcoded True (the satellite fix)."""
-    from repro.kernels import pack_bits
+def test_interpret_follows_platform(monkeypatch):
+    """interpret=None: the interpreter on the CPU backend, Mosaic on TPU;
+    an explicit bool always wins."""
+    from repro.kernels import resolve_interpret
 
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    x = jnp.ones((8, 8 * 32), jnp.float32)
-    out = pack_bits.pack_sign_pallas(x, bm=8, bkw=8)  # interpret unset
-    assert np.asarray(out).shape == (8, 8)
-    assert (np.asarray(out) == np.uint32(0xFFFFFFFF)).all()
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert resolve_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    assert resolve_interpret(True) is True
+    assert GemmConfig(backend="vpu")._interpret is False
+
+
+def test_interpret_refuses_other_platforms(monkeypatch):
+    """A platform with no Pallas mode raises instead of falling back to
+    timing the interpreter."""
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        resolve_interpret()
+    with pytest.raises(RuntimeError, match="gpu"):
+        GemmConfig(backend="vpu")._interpret
+    assert resolve_interpret(False) is False
 
 
 # ---------------------------------------------------------------------------
@@ -320,7 +332,7 @@ def test_autotune_cache_wins_over_heuristic(tmp_path, monkeypatch):
     assert dispatch.select_tiles(m, n, kw, "vpu") == won
     # other shapes keep the heuristic table
     assert dispatch.select_tiles(64, 64, 64, "vpu") == dispatch.TileConfig(
-        64, 64, 64, 8)
+        64, 64, 64, 64)
     # plane backends tune their OWN kernel (not the 1-bit down-resolution)
     import dataclasses as dc
 

@@ -17,6 +17,7 @@ from hypothesis import strategies as st
 from repro.core import bitpack, quant
 from repro.kernels import dispatch, ref
 from repro.kernels.dispatch import EpilogueSpec, GemmConfig
+from repro.launch.mesh import make_mesh
 
 WAYS = [1, 2, 4, 8]
 
@@ -275,7 +276,7 @@ def test_shard_prologue_property(k_true, ways, inner):
     (pad bits are 0 in both operands on every shard)."""
     if len(jax.devices()) < ways:
         pytest.skip(f"{ways}-way mesh needs virtual host devices")
-    mesh = jax.make_mesh((ways,), ("model",))
+    mesh = make_mesh((ways,), ("model",))
     m, n = 3, 5
     a, w = _mats(k_true * 5 + ways, m, k_true, n)
     oracle = np.asarray(ref.sign_gemm_ref(a, w)).astype(np.int32)
@@ -303,7 +304,7 @@ def test_pad_correction_property(k_true, ways, inner):
     fixture params from pytest.)"""
     if len(jax.devices()) < ways:
         pytest.skip(f"{ways}-way mesh needs virtual host devices")
-    mesh = jax.make_mesh((ways,), ("model",))
+    mesh = make_mesh((ways,), ("model",))
     m, n = 3, 5
     a, w = _mats(k_true * 7 + ways, m, k_true, n)
     oracle = np.asarray(ref.sign_gemm_ref(a, w)).astype(np.int32)

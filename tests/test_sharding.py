@@ -2,14 +2,14 @@
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.dist.sharding import Resolver
 
 
 def _mesh(shape=(16, 16), axes=("data", "model")):
-    return abstract_mesh(shape, axes)
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def test_divisible_dims_shard():

@@ -2,12 +2,15 @@
 resume, convert to the packed serving format, and serve — the full BMXNet
 lifecycle (train with floats -> pack bits -> serve with xnor)."""
 
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.ckpt.manager import CheckpointManager
 from repro.core import converter
@@ -77,11 +80,39 @@ def test_train_launcher_cli(tmp_path):
          "--ckpt-every", "3", "--log-every", "2",
          "--export-packed", str(tmp_path / "packed.npz")],
         capture_output=True, text=True, timeout=540,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
-             "HOME": "/root"},
-        cwd="/root/repo",
+        env={**os.environ, "PYTHONPATH": "src"},
+        cwd=Path(__file__).parents[1],
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "loss=" in out.stdout
     assert "packed export" in out.stdout
     assert (tmp_path / "packed.npz").exists()
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_location(env_dir, tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins and no other path is set in code;
+    unset, the cache lives in the checkout's .jax_cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch import compile_cache
+
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.use_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == saved[0]
+        else:
+            assert got == str(Path(__file__).parents[1] / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          saved[1])
+        compilation_cache.reset_cache()

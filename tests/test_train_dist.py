@@ -14,7 +14,7 @@ from hypothesis import strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.ckpt.manager import CheckpointManager
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.policy import PolicySchedule, QuantPolicy
 from repro.data import synthetic
 from repro.dist import compress
@@ -23,6 +23,7 @@ from repro.nn.common import QCtx
 from repro.optim import adamw
 from repro.train import tracker as tracker_mod
 from repro.train import trainer
+from repro.launch.mesh import make_mesh
 
 
 def _n_dev():
@@ -32,7 +33,7 @@ def _n_dev():
 def _mesh_or_skip(dp, tp=1):
     if _n_dev() < dp * tp:
         pytest.skip(f"needs {dp * tp} devices, have {_n_dev()}")
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return make_mesh((dp, tp), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -48,7 +49,7 @@ def _sim_member(g, e):
     return c, acc - c
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)  # each draw compiles a mesh step
 @given(seed=st.integers(0, 2**31 - 1), dp=st.sampled_from([2, 4, 8]))
 def test_compressed_psum_property(seed, dp):
     """Per-member EF residual locality + the EF-SGD invariant, on a real
@@ -57,7 +58,7 @@ def test_compressed_psum_property(seed, dp):
     simulation, and mean(true) - mean(compressed) == mean(residual)."""
     if _n_dev() < dp:
         return  # this draw needs a bigger rig; other draws still run
-    mesh = jax.make_mesh((dp,), ("data",))
+    mesh = make_mesh((dp,), ("data",))
     rng = np.random.default_rng(seed)
     shapes = {"a": (3, 5), "b": (7,), "c": (2, 2, 4)}
     g = {k: (rng.standard_normal((dp,) + s) * rng.uniform(0.1, 10.0))
@@ -165,7 +166,9 @@ def test_compressed_resume_bit_identical(tmp_path):
 
 def test_dp_tp_2d_mesh_uncompressed_matches_single_device():
     """A 2-D ('data','model') mesh passes the model axis through
-    replicated: DP=2 x TP=2 uncompressed == single-device microbatch=2."""
+    replicated: DP=2 x TP=2 uncompressed == single-device microbatch=2, to
+    fp32 rounding (the psum reorders the gradient sum — see
+    test_train_integration.test_dp_uncompressed_bit_identical)."""
     mesh = _mesh_or_skip(2, tp=2)
     spec, cfg, ctx, opt, dcfg = _setup()
 
@@ -181,9 +184,11 @@ def test_dp_tp_2d_mesh_uncompressed_matches_single_device():
             b = synthetic.batch_at(dcfg, i)
             params, opt_state, ms = single(params, opt_state, b)
             state, md = sharded(state, b)
-            assert float(ms["loss"]) == float(md["loss"])
+            np.testing.assert_allclose(float(ms["loss"]), float(md["loss"]),
+                                       rtol=1e-6, atol=1e-6)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(state.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

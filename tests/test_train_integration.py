@@ -14,6 +14,7 @@ from repro.models import registry
 from repro.nn.common import QCtx
 from repro.optim import adamw
 from repro.train import trainer
+from repro.launch.mesh import make_mesh
 
 
 def _run(quant, steps=60, arch="deepseek-7b", lr=6e-3):
@@ -96,17 +97,24 @@ def test_microbatch_aux_metrics_parity():
     np.testing.assert_allclose(float(m1["ce"]), float(m4["ce"]), rtol=2e-3)
 
 
+# DP vs single-device: the same sums in another reduction order differ by
+# a few fp32 ULPs (eps 1.2e-7) per step; 1e-6 allows ~8 ULPs at unit scale
+_DP_TOL = dict(rtol=1e-6, atol=1e-6)
+
+
 def _dp_mesh_or_skip(dp):
     if len(jax.devices()) < dp:
         pytest.skip(f"needs {dp} devices, have {len(jax.devices())}")
-    return jax.make_mesh((dp, 1), ("data", "model"))
+    return make_mesh((dp, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("dp", [2, 4, 8])
 def test_dp_uncompressed_bit_identical(dp):
-    """The uncompressed DP step is BIT-identical to the single-device step
-    with microbatch=dp at every split: XLA's psum over 'data' continues
-    the same left-fold reduction order as the microbatch scan."""
+    """The uncompressed DP step matches the single-device step with
+    microbatch=dp at every split, to fp32 rounding.  Not bit for bit:
+    XLA's psum over 'data' sums the members' gradients in its own order,
+    not the microbatch scan's left fold, and a reordered fp32 sum differs
+    in the last place (1 ULP, 5.96e-8 abs, observed) — hence _DP_TOL."""
     mesh = _dp_mesh_or_skip(dp)
     spec = registry.get("granite-3-2b")
     cfg = spec.smoke
@@ -126,9 +134,10 @@ def test_dp_uncompressed_bit_identical(dp):
             batch = synthetic.batch_at(dcfg, i)
             params, opt_state, ms = single(params, opt_state, batch)
             state, md = sharded(state, batch)
-            assert float(ms["loss"]) == float(md["loss"]), (i, dp)
+            np.testing.assert_allclose(float(ms["loss"]), float(md["loss"]),
+                                       **_DP_TOL)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(state.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **_DP_TOL)
 
 
 def test_dp_compressed_learns():
