@@ -1,0 +1,7 @@
+"""Model step: mean device time of one execution of the engine's jitted
+chunked-prefill window (``Engine._window``), from the trace."""
+
+
+def read(obs):
+    runs = obs.trace.executions("jit__window") if obs.trace else []
+    return 1e3 * sum(runs) / len(runs) if runs else None
