@@ -339,7 +339,7 @@ def rows(small: bool = False):
         "speedup": round(ct_tps / fx_tps, 2),
         "fixed_ttft_ms_mean": round(float(np.mean(fx_ttfts)) * 1e3, 1),
         "cont_ttft_ms_mean": round(
-            float(np.mean(list(stats.t_first.values()))) * 1e3, 1),
+            float(np.mean(stats.ttfts())) * 1e3, 1),
         "cont_ttft_ms_p50": _pct(stats.ttfts(), 50),
         "cont_ttft_ms_p95": _pct(stats.ttfts(), 95),
         "cont_tpot_ms_p50": _pct(stats.tpots(), 50),

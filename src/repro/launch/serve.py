@@ -222,7 +222,7 @@ def main() -> None:
         dt = time.time() - t0
         stats = sched.stats
         n_tok = sum(len(v) for v in results.values())
-        ttft = np.mean(list(stats.t_first.values())) * 1e3
+        ttft = np.mean(stats.ttfts()) * 1e3
         print(f"served {len(results)} requests (prompt lens {min(lens)}-"
               f"{max(lens)}) on {args.prompts} slots in {dt:.2f}s: "
               f"{n_tok} tokens ({n_tok / dt:.1f} tok/s), "
@@ -240,6 +240,12 @@ def main() -> None:
         if tpots:
             p50, p95 = np.percentile(tpots, [50, 95]) * 1e3
             print(f"per-token latency: p50 {p50:.1f}ms, p95 {p95:.1f}ms")
+        it = max(stats.iterations, 1)
+        a50, a95 = np.percentile(stats.admit_ttfts(), [50, 95]) * 1e3
+        print(f"scheduler: {stats.iterations} iterations, host "
+              f"{stats.host_s / it * 1e3:.2f}ms and waiting on the device "
+              f"{stats.wait_s / it * 1e3:.2f}ms per iteration; admission to "
+              f"first token p50 {a50:.1f}ms, p95 {a95:.1f}ms")
         for rid in sorted(results)[:4]:
             print(f"  rid={rid} ({len(results[rid])} tok): "
                   f"{results[rid][:10]}")

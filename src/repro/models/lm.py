@@ -452,60 +452,68 @@ def decode_step(
 
     ``kv`` selects the attention cache layout; ``write_mask`` (B,) bool
     drops cache writes for inactive batch rows (required on the paged
-    layout, where recycled blocks make junk writes unsafe)."""
-    x = params["embed"]["table"].astype(ctx.compute_dtype)[tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model**0.5, ctx.compute_dtype)
-    if cfg.embed_norm:
-        x = norm_apply(cfg.norm, params["embed_ln"], x)
+    layout, where recycled blocks make junk writes unsafe).
+
+    Named scopes ``embed``, ``attn`` (the mixer, of every kind), ``ffn``
+    and ``head``, the same in every layer, label the step's device ops in
+    a profiler trace."""
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"].astype(ctx.compute_dtype)[tokens]
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model**0.5, ctx.compute_dtype)
+        if cfg.embed_norm:
+            x = norm_apply(cfg.norm, params["embed_ln"], x)
 
     new_layers = []
     for i, blk in enumerate(params["layers"]):
         path = f"layers/{i}"
         lc = dict(cache["layers"][i])
-        h = norm_apply(cfg.norm, blk["pre_norm"], x)
-        kind = cfg.mixer_kind(i)
-        if kind in ("attn", "local_attn"):
-            acfg = cfg.attn if kind == "attn" else cfg.local_attn
-            h, ac = attn_lib.attn_decode(
-                blk["attn"], h, pos, lc, acfg, ctx, f"{path}/attn",
-                kv=kv if kind == "attn" else None, write_mask=write_mask,
-            )
-            lc.update(ac)
-        elif kind == "rglru":
-            h, rc = rglru_lib.rglru_decode(
-                blk["rglru"], h, lc, cfg.rglru, ctx, f"{path}/rglru"
-            )
-            lc.update(rc)
-        elif kind == "rwkv6":
-            h, tc = rwkv_lib.timemix_decode(
-                blk["tmix"], h,
-                {"S": lc["S"], "shift": lc["shift"]},
-                cfg.rwkv, ctx, f"{path}/tmix",
-            )
-            lc.update(tc)
-        if cfg.post_norm:
-            h = norm_apply(cfg.norm, blk["post_mixer_norm"], h)
-        x = x + h
+        with jax.named_scope("attn"):
+            h = norm_apply(cfg.norm, blk["pre_norm"], x)
+            kind = cfg.mixer_kind(i)
+            if kind in ("attn", "local_attn"):
+                acfg = cfg.attn if kind == "attn" else cfg.local_attn
+                h, ac = attn_lib.attn_decode(
+                    blk["attn"], h, pos, lc, acfg, ctx, f"{path}/attn",
+                    kv=kv if kind == "attn" else None, write_mask=write_mask,
+                )
+                lc.update(ac)
+            elif kind == "rglru":
+                h, rc = rglru_lib.rglru_decode(
+                    blk["rglru"], h, lc, cfg.rglru, ctx, f"{path}/rglru"
+                )
+                lc.update(rc)
+            elif kind == "rwkv6":
+                h, tc = rwkv_lib.timemix_decode(
+                    blk["tmix"], h,
+                    {"S": lc["S"], "shift": lc["shift"]},
+                    cfg.rwkv, ctx, f"{path}/tmix",
+                )
+                lc.update(tc)
+            if cfg.post_norm:
+                h = norm_apply(cfg.norm, blk["post_mixer_norm"], h)
+            x = x + h
 
-        h = norm_apply(cfg.norm, blk["pre_ffn_norm"], x)
-        fkind = cfg.ffn_kind(i)
-        if fkind == "rwkv_cmix":
-            h = rwkv_lib.chanmix_forward(
-                blk["cmix"], h, cfg.rwkv, ctx, f"{path}/cmix",
-                shift_state=lc["cm_shift"],
-            )
-            lc["cm_shift"] = norm_apply(
-                cfg.norm, blk["pre_ffn_norm"], x
-            )[:, 0].astype(lc["cm_shift"].dtype)
-        else:
-            h, _ = _ffn_forward(blk, i, h, cfg, ctx, path)
-        if cfg.post_norm:
-            h = norm_apply(cfg.norm, blk["post_ffn_norm"], h)
-        x = x + h
+        with jax.named_scope("ffn"):
+            h = norm_apply(cfg.norm, blk["pre_ffn_norm"], x)
+            fkind = cfg.ffn_kind(i)
+            if fkind == "rwkv_cmix":
+                h = rwkv_lib.chanmix_forward(
+                    blk["cmix"], h, cfg.rwkv, ctx, f"{path}/cmix",
+                    shift_state=lc["cm_shift"],
+                )
+                lc["cm_shift"] = norm_apply(
+                    cfg.norm, blk["pre_ffn_norm"], x
+                )[:, 0].astype(lc["cm_shift"].dtype)
+            else:
+                h, _ = _ffn_forward(blk, i, h, cfg, ctx, path)
+            if cfg.post_norm:
+                h = norm_apply(cfg.norm, blk["post_ffn_norm"], h)
+            x = x + h
         new_layers.append(lc)
 
-    logits = _logits(params, cfg, ctx, x)
+    with jax.named_scope("head"):
+        logits = _logits(params, cfg, ctx, x)
     return logits, {"layers": new_layers}
 
 
@@ -535,13 +543,15 @@ def decode_window(
     returns every position's logits (B, C, V) instead, which is how the
     speculative target scores all C proposed continuations in ONE call
     (logit row c conditions on window tokens <= c via the causal mask —
-    exactly the sequential decode distribution at each position)."""
+    exactly the sequential decode distribution at each position).  Named
+    scopes as in :func:`decode_step`."""
     b, c = tokens.shape
-    x = params["embed"]["table"].astype(ctx.compute_dtype)[tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model**0.5, ctx.compute_dtype)
-    if cfg.embed_norm:
-        x = norm_apply(cfg.norm, params["embed_ln"], x)
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"].astype(ctx.compute_dtype)[tokens]
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model**0.5, ctx.compute_dtype)
+        if cfg.embed_norm:
+            x = norm_apply(cfg.norm, params["embed_ln"], x)
     positions = pos_start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
 
     new_layers = []
@@ -552,24 +562,28 @@ def decode_window(
                 f"decode_window supports pure-attention stacks; layer {i} "
                 f"is {cfg.mixer_kind(i)!r}")
         lc = dict(cache["layers"][i])
-        h = norm_apply(cfg.norm, blk["pre_norm"], x)
-        h, ac = attn_lib.attn_window(
-            blk["attn"], h, positions, lc, cfg.attn, ctx, f"{path}/attn",
-            kv, write_mask=write_mask,
-        )
-        lc.update(ac)
-        if cfg.post_norm:
-            h = norm_apply(cfg.norm, blk["post_mixer_norm"], h)
-        x = x + h
+        with jax.named_scope("attn"):
+            h = norm_apply(cfg.norm, blk["pre_norm"], x)
+            h, ac = attn_lib.attn_window(
+                blk["attn"], h, positions, lc, cfg.attn, ctx, f"{path}/attn",
+                kv, write_mask=write_mask,
+            )
+            lc.update(ac)
+            if cfg.post_norm:
+                h = norm_apply(cfg.norm, blk["post_mixer_norm"], h)
+            x = x + h
 
-        h = norm_apply(cfg.norm, blk["pre_ffn_norm"], x)
-        h, _ = _ffn_forward(blk, i, h, cfg, ctx, path)
-        if cfg.post_norm:
-            h = norm_apply(cfg.norm, blk["post_ffn_norm"], h)
-        x = x + h
+        with jax.named_scope("ffn"):
+            h = norm_apply(cfg.norm, blk["pre_ffn_norm"], x)
+            h, _ = _ffn_forward(blk, i, h, cfg, ctx, path)
+            if cfg.post_norm:
+                h = norm_apply(cfg.norm, blk["post_ffn_norm"], h)
+            x = x + h
         new_layers.append(lc)
 
-    logits = _logits(params, cfg, ctx, x if logits_all else x[:, -1:, :])
+    with jax.named_scope("head"):
+        logits = _logits(params, cfg, ctx,
+                         x if logits_all else x[:, -1:, :])
     return logits, {"layers": new_layers}
 
 

@@ -750,16 +750,18 @@ def attn_decode(
             q = rope(q, positions, cfg.rope_theta)
     else:
         q, k_new, v_new = _project_qkv(params, x, positions, cfg, ctx, path)
-        cache = kv.fill(cache, k_new, v_new, positions, write_mask)
+        with jax.named_scope("kv_write"):
+            cache = kv.fill(cache, k_new, v_new, positions, write_mask)
 
     qg = q.reshape(b, 1, cfg.n_kv_heads, cfg.groups, cfg.d_head)
-    if cfg.fused_attn and not cross:
-        out = kv.attend(cache, qg, positions, cfg,
-                        interpret=ctx.gemm_config._interpret)
-    else:
-        k, v, k_pos = kv.gather(cache)
-        mask = _mask(cfg, positions, k_pos)  # (B, 1, L)
-        out = _sdpa(cfg, qg, k, v, mask)
+    with jax.named_scope("attend"):
+        if cfg.fused_attn and not cross:
+            out = kv.attend(cache, qg, positions, cfg,
+                            interpret=ctx.gemm_config._interpret)
+        else:
+            k, v, k_pos = kv.gather(cache)
+            mask = _mask(cfg, positions, k_pos)  # (B, 1, L)
+            out = _sdpa(cfg, qg, k, v, mask)
     out = out.reshape(b, 1, cfg.n_heads * cfg.d_head).astype(ctx.compute_dtype)
     return ctx.dense(params["o"], out, f"{path}/o"), cache
 
@@ -786,14 +788,16 @@ def attn_window(
     verify window (per-row starts — hence ``fill_window``)."""
     b, c, _ = x.shape
     q, k_new, v_new = _project_qkv(params, x, positions, cfg, ctx, path)
-    cache = kv.fill_window(cache, k_new, v_new, positions, write_mask)
+    with jax.named_scope("kv_write"):
+        cache = kv.fill_window(cache, k_new, v_new, positions, write_mask)
     qg = q.reshape(b, c, cfg.n_kv_heads, cfg.groups, cfg.d_head)
-    if cfg.fused_attn:
-        out = kv.attend(cache, qg, positions, cfg,
-                        interpret=ctx.gemm_config._interpret)
-    else:
-        k, v, k_pos = kv.gather(cache)
-        mask = _mask(cfg, positions, k_pos)  # (B, C, L)
-        out = _sdpa(cfg, qg, k, v, mask)
+    with jax.named_scope("attend"):
+        if cfg.fused_attn:
+            out = kv.attend(cache, qg, positions, cfg,
+                            interpret=ctx.gemm_config._interpret)
+        else:
+            k, v, k_pos = kv.gather(cache)
+            mask = _mask(cfg, positions, k_pos)  # (B, C, L)
+            out = _sdpa(cfg, qg, k, v, mask)
     out = out.reshape(b, c, cfg.n_heads * cfg.d_head).astype(ctx.compute_dtype)
     return ctx.dense(params["o"], out, f"{path}/o"), cache

@@ -159,11 +159,24 @@ rejection-sampling correction, out of scope), lm family, pure-attention
 stacks (``decode_window``/``cache_truncate`` restriction).  The draft
 always keeps its own CONTIGUOUS cache, even under a paged target — it is
 slot-private scratch state, block sharing buys nothing there.
+
+**Host spans**: each loop iteration is a profiler step span ``sched.step``
+(args: ``step_num``; ``t_us``, the ``SchedulerStats`` clock at entry in
+microseconds, which places every stats timestamp on the trace; the rows
+prefilling and decoding at entry; ``host_us``/``wait_us``, the counters at
+entry).  Inside it ``sched.admit``, ``sched.window``, ``sched.decode`` and
+``sched.emit`` cover admission, window and decode dispatch, and
+emission/retirement; ``sched.sync`` covers every blocking device-to-host
+read, the only place the loop waits on the chip.  Without a profiler
+session a span costs about a microsecond.  ``SchedulerStats.iterations``,
+``host_s`` and ``wait_s`` count the same split with no profiler; a
+dispatch that blocks on the device counts as host time.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -346,13 +359,18 @@ class SchedulerStats:
     prefill_tokens: int = 0  # prompt tokens actually prefilled (paged)
     shared_tokens: int = 0  # prompt tokens skipped via prefix sharing
     admissions: list = dataclasses.field(default_factory=list)  # (rid, slot)
-    t_first: dict = dataclasses.field(default_factory=dict)  # rid -> s
+    t_admit: dict = dataclasses.field(default_factory=dict)  # rid -> s
     t_done: dict = dataclasses.field(default_factory=dict)  # rid -> s
     # per-request emission timestamps (rid -> [s], one per emitted token,
     # relative to run start).  TTFT = first entry; TPOT = the diffs — in
     # spec mode an accepted run lands in one burst, so the TPOT
     # distribution is exactly what speculative decoding reshapes.
     t_tokens: dict = dataclasses.field(default_factory=dict)
+    # loop iterations and their seconds on the host outside ``sched.sync``
+    # and blocked inside it (module docstring: host spans)
+    iterations: int = 0
+    host_s: float = 0.0
+    wait_s: float = 0.0
     # speculative-mode counters (zero when no draft is configured)
     spec_rounds: int = 0  # per-slot verify outcomes scored
     spec_proposed: int = 0  # draft tokens proposed (spec_len * rounds)
@@ -361,6 +379,12 @@ class SchedulerStats:
     def ttfts(self) -> list:
         """Per-request time-to-first-token (seconds, run-relative)."""
         return [v[0] for v in self.t_tokens.values() if v]
+
+    def admit_ttfts(self) -> list:
+        """Per-request seconds from admission to first token (the TTFT
+        without the queue wait)."""
+        return [v[0] - self.t_admit[r] for r, v in self.t_tokens.items()
+                if v and r in self.t_admit]
 
     def tpots(self) -> list:
         """Per-token inter-emission gaps (seconds), pooled over requests
@@ -751,7 +775,6 @@ class Scheduler:
         self.queue: collections.deque[Request] = collections.deque()
         self.slots: list[SlotState | None] = [None] * engine.ecfg.batch
         self.stats = SchedulerStats()
-        self.last_stats = self.stats  # refreshed (same object) by run()
         self._results: dict[int, np.ndarray] = {}
         self._next_rid = 0
         # spec mode: token at pos-1 per slot (the draft restart window
@@ -790,8 +813,6 @@ class Scheduler:
         """Record one emitted token; retire the slot on eos / budget
         exhaustion.  Returns True when the slot retired."""
         now = self._now()
-        if not st.tokens:
-            self.stats.t_first[st.rid] = now
         self.stats.t_tokens.setdefault(st.rid, []).append(now)
         st.tokens.append(token)
         st.budget -= 1
@@ -801,6 +822,39 @@ class Scheduler:
             return True
         return False
 
+    @contextlib.contextmanager
+    def _step(self):
+        """One loop iteration: the ``sched.step`` span, and the iteration's
+        seconds outside ``sched.sync`` added to ``stats.host_s``."""
+        st = self.stats
+        phases = [s.phase for s in self.slots if s is not None]
+        t, wait = time.perf_counter(), st.wait_s
+        with jax.profiler.StepTraceAnnotation(
+                "sched.step", step_num=st.iterations,
+                t_us=(t - self._t0) * 1e6,
+                prefill_rows=phases.count("prefill"),
+                decode_rows=phases.count("decode"),
+                host_us=st.host_s * 1e6, wait_us=st.wait_s * 1e6):
+            yield
+        st.iterations += 1
+        st.host_s += time.perf_counter() - t - (st.wait_s - wait)
+
+    def _admitting(self):
+        """The ``sched.admit`` span: how many requests the free slots take
+        from the queue, and the first one's rid."""
+        n = min(self.slots.count(None), len(self.queue))
+        return jax.profiler.TraceAnnotation(
+            "sched.admit", n=n, rid=self.queue[0].rid if n else -1)
+
+    def _fetch(self, x: jax.Array) -> np.ndarray:
+        """Copy ``x`` to the host: the one place the loop waits on the
+        device (``sched.sync``, counted in ``stats.wait_s``)."""
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("sched.sync"):
+            out = np.asarray(x)
+        self.stats.wait_s += time.perf_counter() - t
+        return out
+
     def _sample_for(self, logits, states, active=None) -> np.ndarray:
         """Sample one token per row.  Row ``r`` draws from the key stream
         ``fold_in(fold_in(PRNGKey(seed_r), rid_r), n_emitted_r)`` — a
@@ -809,7 +863,7 @@ class Scheduler:
         temps = [float(st.temperature) if st is not None else 0.0
                  for st in states]
         if all(t <= 0 for t in temps):
-            return np.asarray(self.eng._sample(logits, None, None, active))
+            return self._fetch(self.eng._sample(logits, None, None, active))
         keys = jnp.stack([
             jax.random.fold_in(
                 jax.random.fold_in(jax.random.PRNGKey(st.seed), st.rid),
@@ -817,7 +871,7 @@ class Scheduler:
             if st is not None and st.temperature > 0
             else jax.random.PRNGKey(0)
             for st in states])
-        return np.asarray(self.eng._sample(
+        return self._fetch(self.eng._sample(
             logits, keys, jnp.asarray(temps, jnp.float32), active))
 
     def _new_state(self, r: Request) -> SlotState:
@@ -857,6 +911,7 @@ class Scheduler:
                    and len(self.queue[0].prompt) == head_len):
                 group.append(self.queue.popleft())
             taken, free = free[:len(group)], free[len(group):]
+            t_admit = self._now()
 
             prompts = np.stack([np.asarray(r.prompt) for r in group])
             kw = {
@@ -885,6 +940,7 @@ class Scheduler:
                 st = states[g]
                 self.slots[i] = st
                 self.stats.admissions.append((st.rid, i))
+                self.stats.t_admit[st.rid] = t_admit
                 if st.budget <= 0:  # zero-token request: empty stream
                     self._retire(i, st)
                     free.append(i)
@@ -909,26 +965,32 @@ class Scheduler:
         pos = np.zeros((b,), np.int32)
 
         while self.queue or any(s is not None for s in self.slots):
-            cache, tok, pos = self._admit(cache, tok, pos)
-            active = np.array([s is not None for s in self.slots])
-            if not active.any():
-                continue  # everything admitted retired on its first token
-            if eng.speculative:
-                cache, tok, pos = self._spec_round(cache, tok, pos, active)
-                continue
-            logits, cache = eng._decode(
-                eng.params, cache, jnp.asarray(tok)[:, None],
-                jnp.asarray(pos))
-            sampled = self._sample_for(logits, self.slots,
-                                       jnp.asarray(active))
-            self.stats.steps += 1
-            pos = np.where(active, pos + 1, pos).astype(np.int32)
-            tok = np.where(active, sampled, tok).astype(np.int32)
-            for i in range(b):
-                st = self.slots[i]
-                if st is not None and self._emit(i, st, int(sampled[i])):
-                    cache = eng._reset(cache, jnp.int32(i))
-        self.last_stats = self.stats
+            with self._step():
+                with self._admitting():
+                    cache, tok, pos = self._admit(cache, tok, pos)
+                active = np.array([s is not None for s in self.slots])
+                if not active.any():
+                    continue  # everything admitted retired on its first token
+                if eng.speculative:
+                    cache, tok, pos = self._spec_round(cache, tok, pos,
+                                                       active)
+                    continue
+                with jax.profiler.TraceAnnotation("sched.decode",
+                                                  rows=int(active.sum())):
+                    logits, cache = eng._decode(
+                        eng.params, cache, jnp.asarray(tok)[:, None],
+                        jnp.asarray(pos))
+                sampled = self._sample_for(logits, self.slots,
+                                           jnp.asarray(active))
+                self.stats.steps += 1
+                with jax.profiler.TraceAnnotation("sched.emit"):
+                    pos = np.where(active, pos + 1, pos).astype(np.int32)
+                    tok = np.where(active, sampled, tok).astype(np.int32)
+                    for i in range(b):
+                        st = self.slots[i]
+                        if (st is not None
+                                and self._emit(i, st, int(sampled[i]))):
+                            cache = eng._reset(cache, jnp.int32(i))
         return self._results
 
     # -- speculative rounds (shared by both cache layouts) ------------------
@@ -953,14 +1015,14 @@ class Scheduler:
             eng.dparams, self._dcache,
             jnp.asarray(np.stack([self._prev, tok], axis=1)),
             jnp.asarray(pos - 1), dm)
-        cur = np.asarray(eng._sample(d_logits, None, None, dm))
+        cur = self._fetch(eng._sample(d_logits, None, None, dm))
         props[:, 0] = cur
         dpos = pos + 1
         for j in range(1, s_len):
             d_logits, self._dcache = eng._d_step(
                 eng.dparams, self._dcache, jnp.asarray(cur)[:, None],
                 jnp.asarray(dpos), dm)
-            cur = np.asarray(eng._sample(d_logits, None, None, dm))
+            cur = self._fetch(eng._sample(d_logits, None, None, dm))
             props[:, j] = cur
             dpos = dpos + 1
 
@@ -968,7 +1030,7 @@ class Scheduler:
         logits, cache = eng._verify(eng.params, cache, jnp.asarray(win),
                                     jnp.asarray(pos), dm)
         self.stats.steps += 1
-        greedy = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+        greedy = self._fetch(jnp.argmax(logits, axis=-1)).astype(np.int32)
 
         lengths = np.full((b,), self.NO_TRUNC, np.int32)
         rolled = False
@@ -1038,6 +1100,7 @@ class Scheduler:
             st.phase = "prefill"
             st.prompt = prompt
             self.stats.admissions.append((st.rid, i))
+            self.stats.t_admit[st.rid] = self._now()
             if st.budget <= 0:  # zero-token request: empty stream
                 self.slots[i] = st
                 self._retire(i, st)
@@ -1085,17 +1148,19 @@ class Scheduler:
         b = ecfg.batch
         c = min([self.slots[i].prompt_len - self.slots[i].prefill_done
                  for i in pre] + [chunk])
-        tokens = np.zeros((b, c), np.int32)
-        pos_start = np.zeros((b,), np.int32)
-        wm = np.zeros((b,), bool)
-        for i in pre:
-            st = self.slots[i]
-            tokens[i] = st.prompt[st.prefill_done:st.prefill_done + c]
-            pos_start[i] = st.prefill_done
-            wm[i] = True
-        logits, cache = eng._window(
-            eng.params, cache, jnp.asarray(tokens), jnp.asarray(pos_start),
-            jnp.asarray(wm))
+        with jax.profiler.TraceAnnotation("sched.window", rows=len(pre),
+                                          width=c):
+            tokens = np.zeros((b, c), np.int32)
+            pos_start = np.zeros((b,), np.int32)
+            wm = np.zeros((b,), bool)
+            for i in pre:
+                st = self.slots[i]
+                tokens[i] = st.prompt[st.prefill_done:st.prefill_done + c]
+                pos_start[i] = st.prefill_done
+                wm[i] = True
+            logits, cache = eng._window(
+                eng.params, cache, jnp.asarray(tokens),
+                jnp.asarray(pos_start), jnp.asarray(wm))
         self.stats.prefills += 1
         self.stats.prefill_tokens += c * len(pre)
         fin = [i for i in pre
@@ -1106,31 +1171,34 @@ class Scheduler:
             first = self._sample_for(
                 logits, states,
                 jnp.asarray([s is not None for s in states]))
-        for i in pre:
-            st = self.slots[i]
-            st.prefill_done += c
-            if st.prefill_done < st.prompt_len:
-                continue
-            if ecfg.shared_prefix:
-                # the slot's own full prompt blocks are now written; make
-                # them discoverable for later identical-prefix requests
-                for j in range(st.n_shared, len(st.block_hashes)):
-                    self.alloc.register(st.blocks[j], st.block_hashes[j])
-            st.phase = "decode"
-            if eng.speculative:
-                # the draft keeps its own (contiguous) prefill of the full
-                # prompt; the width-2 restart window re-syncs it each round
-                _, d_sub = eng._d_prefill(
-                    eng.dparams, jnp.asarray(st.prompt[None], jnp.int32))
-                self._dcache = eng._d_insert(
-                    self._dcache, d_sub, jnp.asarray([i], jnp.int32))
-                self._prev[i] = int(st.prompt[-1])
-            st.prompt = None  # the cache holds it now
-            if self._emit(i, st, int(first[i])):
-                cache = self._release_slot(cache, i, st)
-            else:
-                tok[i] = first[i]
-                pos[i] = st.prompt_len
+        with jax.profiler.TraceAnnotation("sched.emit"):
+            for i in pre:
+                st = self.slots[i]
+                st.prefill_done += c
+                if st.prefill_done < st.prompt_len:
+                    continue
+                if ecfg.shared_prefix:
+                    # the slot's own full prompt blocks are now written;
+                    # make them discoverable for later identical-prefix
+                    # requests
+                    for j in range(st.n_shared, len(st.block_hashes)):
+                        self.alloc.register(st.blocks[j], st.block_hashes[j])
+                st.phase = "decode"
+                if eng.speculative:
+                    # the draft keeps its own (contiguous) prefill of the
+                    # full prompt; the width-2 restart window re-syncs it
+                    # each round
+                    _, d_sub = eng._d_prefill(
+                        eng.dparams, jnp.asarray(st.prompt[None], jnp.int32))
+                    self._dcache = eng._d_insert(
+                        self._dcache, d_sub, jnp.asarray([i], jnp.int32))
+                    self._prev[i] = int(st.prompt[-1])
+                st.prompt = None  # the cache holds it now
+                if self._emit(i, st, int(first[i])):
+                    cache = self._release_slot(cache, i, st)
+                else:
+                    tok[i] = first[i]
+                    pos[i] = st.prompt_len
         return cache, tok, pos
 
     def _run_paged(self) -> dict[int, np.ndarray]:
@@ -1145,34 +1213,38 @@ class Scheduler:
         chunk = ecfg.prefill_chunk or ecfg.cache_len
 
         while self.queue or any(s is not None for s in self.slots):
-            cache = self._admit_paged(cache)
-            pre = [i for i, s in enumerate(self.slots)
-                   if s is not None and s.phase == "prefill"]
-            if pre:
-                cache, tok, pos = self._prefill_chunk(cache, tok, pos,
-                                                      pre, chunk)
-            dec = np.array([s is not None and s.phase == "decode"
-                            for s in self.slots])
-            if not dec.any():
-                continue  # all slots still prefilling (or just drained)
-            if eng.speculative:
-                cache, tok, pos = self._spec_round(cache, tok, pos, dec)
-                continue
-            logits, cache = eng._decode(
-                eng.params, cache, jnp.asarray(tok)[:, None],
-                jnp.asarray(pos), jnp.asarray(dec))
-            states = [s if (s is not None and s.phase == "decode") else None
-                      for s in self.slots]
-            sampled = self._sample_for(logits, states, jnp.asarray(dec))
-            self.stats.steps += 1
-            pos = np.where(dec, pos + 1, pos).astype(np.int32)
-            tok = np.where(dec, sampled, tok).astype(np.int32)
-            for i in range(b):
-                st = self.slots[i]
-                if (st is not None and st.phase == "decode"
-                        and self._emit(i, st, int(sampled[i]))):
-                    cache = self._release_slot(cache, i, st)
-        self.last_stats = self.stats
+            with self._step():
+                with self._admitting():
+                    cache = self._admit_paged(cache)
+                pre = [i for i, s in enumerate(self.slots)
+                       if s is not None and s.phase == "prefill"]
+                if pre:
+                    cache, tok, pos = self._prefill_chunk(cache, tok, pos,
+                                                          pre, chunk)
+                dec = np.array([s is not None and s.phase == "decode"
+                                for s in self.slots])
+                if not dec.any():
+                    continue  # all slots still prefilling (or just drained)
+                if eng.speculative:
+                    cache, tok, pos = self._spec_round(cache, tok, pos, dec)
+                    continue
+                with jax.profiler.TraceAnnotation("sched.decode",
+                                                  rows=int(dec.sum())):
+                    logits, cache = eng._decode(
+                        eng.params, cache, jnp.asarray(tok)[:, None],
+                        jnp.asarray(pos), jnp.asarray(dec))
+                states = [s if (s is not None and s.phase == "decode")
+                          else None for s in self.slots]
+                sampled = self._sample_for(logits, states, jnp.asarray(dec))
+                self.stats.steps += 1
+                with jax.profiler.TraceAnnotation("sched.emit"):
+                    pos = np.where(dec, pos + 1, pos).astype(np.int32)
+                    tok = np.where(dec, sampled, tok).astype(np.int32)
+                    for i in range(b):
+                        st = self.slots[i]
+                        if (st is not None and st.phase == "decode"
+                                and self._emit(i, st, int(sampled[i]))):
+                            cache = self._release_slot(cache, i, st)
         return self._results
 
 

@@ -79,7 +79,7 @@ def _run(eng, lens, seed=0):
     for i, ln in enumerate(lens):
         sched.submit(Request(prompt=rng.integers(
             0, CFG.vocab_size, (ln,)).astype(np.int32), rid=i))
-    return sched.run(), sched.last_stats
+    return sched.run(), sched.stats
 
 
 # ---------------------------------------------------------------------------
