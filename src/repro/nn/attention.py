@@ -513,6 +513,14 @@ class ContiguousKVCache(KVCache):
             window=cfg.window, kv_tile=tile, interpret=interpret)
 
 
+def live_pages(q_pos, block_size: int, bps: int):
+    """(B, C) query positions -> (B,) leading table entries a causal row
+    may see: page ``j`` holds positions ``[j*bs, (j+1)*bs)``, so no query
+    of row ``b`` sees an entry at or past ``max(q_pos[b]) // bs + 1``.
+    Takes numpy (the scheduler's counter) or jax (the kernel's bound)."""
+    return (q_pos.max(axis=1) // block_size + 1).clip(0, bps)
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedKVCache(KVCache):
     """Block-table paged storage over a shared pool.
@@ -702,18 +710,23 @@ class PagedKVCache(KVCache):
                 pos.reshape(b, bps * bs))
 
     def attend(self, cache, q, q_pos, cfg: AttnConfig, interpret=None):
+        """Fused flash-decode through the table; under the causal mask each
+        row attends only over its :func:`live_pages`."""
         b, c = q_pos.shape
-        cache_len = cache["table"].shape[1] * self.block_size
+        bps = cache["table"].shape[1]
+        cache_len = bps * self.block_size
         spb = attn_kernels.select_attn_tiles(b, c, cache_len, cfg.d_head,
                                              "pgd")
+        live = (live_pages(q_pos, self.block_size, bps) if cfg.causal
+                else None)
         return attn_kernels.flash_decode_paged(
             cache["table"], q, q_pos, cache["pool_k"], cache["pool_v"],
             cache["pool_pos"], cache.get("pool_ks"), cache.get("pool_vs"),
             block_size=self.block_size, kv_bits=self.kv_bits,
             sm_scale=cfg.scale, logit_softcap=cfg.logit_softcap,
             causal=cfg.causal, window=cfg.window,
-            blocks_per_step=min(spb, cache["table"].shape[1]),
-            interpret=interpret)
+            blocks_per_step=min(spb, bps), interpret=interpret,
+            live_pages=live)
 
 
 CONTIGUOUS = ContiguousKVCache()

@@ -167,10 +167,15 @@ prefilling and decoding at entry; ``host_us``/``wait_us``, the counters at
 entry).  Inside it ``sched.admit``, ``sched.window``, ``sched.decode`` and
 ``sched.emit`` cover admission, window and decode dispatch, and
 emission/retirement; ``sched.sync`` covers every blocking device-to-host
-read, the only place the loop waits on the chip.  Without a profiler
-session a span costs about a microsecond.  ``SchedulerStats.iterations``,
-``host_s`` and ``wait_s`` count the same split with no profiler; a
-dispatch that blocks on the device counts as host time.
+read, the only place the loop waits on the chip.  On the paged layout
+``sched.window`` and ``sched.decode`` carry ``live_pages`` (the table
+entries the attention kernel visits, its live bound summed over the
+program's rows) and ``table_pages`` (rows x table entries), which
+``SchedulerStats.attn_pages_live`` / ``attn_pages_table`` accumulate.
+Without a profiler session a span costs about a microsecond.
+``SchedulerStats.iterations``, ``host_s`` and ``wait_s`` count the same
+split with no profiler; a dispatch that blocks on the device counts as
+host time.
 """
 
 from __future__ import annotations
@@ -371,6 +376,11 @@ class SchedulerStats:
     iterations: int = 0
     host_s: float = 0.0
     wait_s: float = 0.0
+    # paged decode steps and prefill windows: table entries the attention
+    # kernel visits (its live bound summed over rows) and the rows' whole
+    # tables (module docstring: host spans)
+    attn_pages_live: int = 0
+    attn_pages_table: int = 0
     # speculative-mode counters (zero when no draft is configured)
     spec_rounds: int = 0  # per-slot verify outcomes scored
     spec_proposed: int = 0  # draft tokens proposed (spec_len * rounds)
@@ -1082,6 +1092,21 @@ class Scheduler:
         st.blocks = self.alloc.trim(st.blocks, 0)
         return self.eng._reset(cache, jnp.int32(i))
 
+    def _attn_pages(self, q_pos: np.ndarray) -> dict:
+        """The span args ``live_pages`` (the attention kernel's live bound,
+        ``attn_lib.live_pages``, summed over the rows of one program with
+        query positions ``q_pos`` (B, C)) and ``table_pages`` (rows x
+        table entries), both added to the stats."""
+        table = q_pos.shape[0] * self.bps
+        live = table
+        acfg = self.eng.cfg.attn
+        if acfg.fused_attn and acfg.causal:
+            live = int(attn_lib.live_pages(
+                q_pos, self.eng.kv.block_size, self.bps).sum())
+        self.stats.attn_pages_live += live
+        self.stats.attn_pages_table += table
+        return {"live_pages": live, "table_pages": table}
+
     def _admit_paged(self, cache):
         """Per-request admission: allocate the slot's block-table row
         (reusing registered prefix blocks when ``shared_prefix`` is on)
@@ -1148,15 +1173,17 @@ class Scheduler:
         b = ecfg.batch
         c = min([self.slots[i].prompt_len - self.slots[i].prefill_done
                  for i in pre] + [chunk])
+        pos_start = np.zeros((b,), np.int32)
+        for i in pre:
+            pos_start[i] = self.slots[i].prefill_done
+        pages = self._attn_pages(pos_start[:, None] + np.arange(c))
         with jax.profiler.TraceAnnotation("sched.window", rows=len(pre),
-                                          width=c):
+                                          width=c, **pages):
             tokens = np.zeros((b, c), np.int32)
-            pos_start = np.zeros((b,), np.int32)
             wm = np.zeros((b,), bool)
             for i in pre:
                 st = self.slots[i]
                 tokens[i] = st.prompt[st.prefill_done:st.prefill_done + c]
-                pos_start[i] = st.prefill_done
                 wm[i] = True
             logits, cache = eng._window(
                 eng.params, cache, jnp.asarray(tokens),
@@ -1228,8 +1255,10 @@ class Scheduler:
                 if eng.speculative:
                     cache, tok, pos = self._spec_round(cache, tok, pos, dec)
                     continue
+                pages = self._attn_pages(pos[:, None])
                 with jax.profiler.TraceAnnotation("sched.decode",
-                                                  rows=int(dec.sum())):
+                                                  rows=int(dec.sum()),
+                                                  **pages):
                     logits, cache = eng._decode(
                         eng.params, cache, jnp.asarray(tok)[:, None],
                         jnp.asarray(pos), jnp.asarray(dec))

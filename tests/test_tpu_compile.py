@@ -15,6 +15,7 @@ imports this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ import pytest
 from repro.kernels import attn_decode, dispatch
 from repro.kernels.dispatch import GemmConfig
 from repro.kernels.pack_bits import pack_sign_pallas, quant_pack_planes_pallas
+from repro.nn.attention import AttnConfig, PagedKVCache
 
 D, FF = 2048, 8192
 KVH, G, DH = 8, 4, 64
@@ -127,6 +129,36 @@ def test_flash_decode_paged(one_chip, kv_bits):
                            kv_bits=kv_bits, sm_scale=DH ** -0.5,
                            interpret=False)
     _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("c", [1, 128])
+def test_flash_decode_paged_live_bound(one_chip, c):
+    """The causal bound ``PagedKVCache.attend`` derives from the query
+    positions reaches the kernel inside its table: the custom call keeps
+    the unbounded call's operands (table, query tile, query positions,
+    then the pool blocks), which readers of the trace take by position."""
+    bps = L // BS
+    nb = B * bps
+    cfg = AttnConfig(d_model=KVH * G * DH, n_heads=KVH * G, n_kv_heads=KVH,
+                     d_head=DH, fused_attn=True)
+    kv = PagedKVCache(block_size=BS)
+
+    def fn(table, q, q_pos, pool_k, pool_v, pool_pos):
+        cache = {"table": table, "pool_k": pool_k, "pool_v": pool_v,
+                 "pool_pos": pool_pos}
+        return kv.attend(cache, q, q_pos, cfg, interpret=False)
+
+    text = _compile(fn, one_chip, ((B, bps), jnp.int32),
+                    ((B, c, KVH, G, DH), jnp.float32), ((B, c), jnp.int32),
+                    ((nb, KVH, BS, DH), jnp.float32),
+                    ((nb, KVH, BS, DH), jnp.float32), ((nb, BS), jnp.int32))
+    call = next(ln for ln in text.splitlines() if "tpu_custom_call" in ln)
+    operands = call.split("operand_layout_constraints=", 1)[1]
+    shapes = re.findall(r"\b([a-z]+[0-9]+)\[([0-9,]*)\]", operands)
+    assert shapes[:4] == [("s32", f"{B},{bps}"),
+                          ("f32", f"{B},{KVH},{c * G},{DH}"),
+                          ("s32", f"{B},{c * G},1"),
+                          ("f32", f"{nb},{KVH},{BS},{DH}")]
 
 
 def test_flash_decode_contig(one_chip):
